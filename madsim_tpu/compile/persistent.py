@@ -19,32 +19,35 @@ from __future__ import annotations
 
 import os
 
+# <checkout>/.jax_cache: a fixed path, so every process of this checkout
+# (tests, chip_smoke.py, campaign workers) finds what another compiled; a
+# directory that moves (a mkdtemp, a corpus dir) never hits
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
 
-def enable_persistent_cache(cache_dir: str | None = None,
-                            min_compile_secs: float | None = None
-                            ) -> str | None:
-    """Point jax at an on-disk compilation cache; idempotent.
 
-    Resolution order: explicit `cache_dir` argument, then the
-    JAX_COMPILATION_CACHE_DIR env var (what `scripts/ci.sh` exports),
-    else no-op (returns None) — callers sprinkle this at harness entry
-    points without forcing a cache on ad-hoc runs. `min_compile_secs`
-    skips persisting trivial programs whose disk round-trip would cost
-    more than the compile: an EXPLICIT value always applies; the 1.0s
-    default applies only when the dir is newly configured, so repeated
-    default-argument calls (harness/simtest makes one per run_seeds)
-    never clobber a threshold the caller chose."""
-    d = cache_dir or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if not d:
-        return None
+def cache_dir() -> str:
+    """The one place the persistent cache lives: JAX_COMPILATION_CACHE_DIR
+    when it is set, else `DEFAULT_CACHE_DIR`."""
+    return os.path.abspath(os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                           or DEFAULT_CACHE_DIR)
+
+
+def enable_persistent_cache(min_compile_secs: float | None = None) -> str:
+    """Point jax at `cache_dir()`; idempotent. Returns the directory.
+
+    `min_compile_secs` skips persisting trivial programs whose disk
+    round-trip would cost more than the compile. Only an explicit value
+    is applied: without one, jax's own setting stands (1 s, or
+    JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS), so the repeated calls
+    from run_seeds/fuzz/run_fused never clobber a floor a caller chose."""
     import jax
-    d = os.path.abspath(d)
+    d = cache_dir()
     os.makedirs(d, exist_ok=True)
-    newly = jax.config.jax_compilation_cache_dir != d
-    if newly:
+    if jax.config.jax_compilation_cache_dir != d:
         jax.config.update("jax_compilation_cache_dir", d)
-    if min_compile_secs is not None or newly:
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs",
-            float(1.0 if min_compile_secs is None else min_compile_secs))
+    if min_compile_secs is not None:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          float(min_compile_secs))
     return d

@@ -229,9 +229,9 @@ def run_seeds(rt: Runtime, seeds, max_steps: int, chunk: int = 512,
     under a permuted lane placement and diffs final states leaf-for-leaf
     (`detsan_check`) — DetSanFailure outranks SimFailure, because a
     crash report from a nondeterministic run is not a repro."""
-    # cross-process compile tier: honor JAX_COMPILATION_CACHE_DIR (what
-    # scripts/ci.sh exports) so cold harness processes reuse warm
-    # executables; no-op when the env var is unset
+    # cross-process compile tier: JAX_COMPILATION_CACHE_DIR, else the
+    # checkout's .jax_cache, so cold harness processes reuse warm
+    # executables
     from ..compile.persistent import enable_persistent_cache
     enable_persistent_cache()
     init = apply_net_override(rt.init_batch(np.asarray(seeds, np.uint32)),

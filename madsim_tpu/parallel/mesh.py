@@ -42,15 +42,3 @@ def first_crash_seed(state, seeds) -> jax.Array:
     lowest = jnp.min(jnp.where(state.crashed, jnp.arange(seeds.shape[0]),
                                big))
     return jnp.where(lowest == big, -1, lowest)
-
-
-def compact(state, seeds):
-    """Drop halted trajectories (host-side gather): returns (live_state,
-    live_seeds). The early-exit compaction of BASELINE.md config 4 — after
-    most seeds finish, re-pack the survivors into a dense smaller batch so
-    lockstep stepping stops wasting lanes on frozen trajectories."""
-    live = ~np.asarray(state.halted)
-    idx = np.nonzero(live)[0]
-    live_state = jax.tree.map(lambda a: jnp.asarray(np.asarray(a)[idx]),
-                              state)
-    return live_state, np.asarray(seeds)[idx]

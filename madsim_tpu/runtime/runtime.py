@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..compile.cache import COMPILE_LOG, PROGRAM_CACHE
+from ..compile.persistent import enable_persistent_cache
 from ..compile.signature import runtime_signature
 from ..core import prng
 from ..core import types as T
@@ -465,6 +466,7 @@ class Runtime:
         re-enter the same fused executable and frozen lanes are
         identity (tests/test_timetravel.py holds it).
         """
+        enable_persistent_cache()
         n_chunks = -(-max_steps // chunk)
         ckpt_every, ckpt_log = self._ckpt_setup(ckpt_every, ckpt_log)
         if ckpt_every is None:
@@ -651,6 +653,18 @@ class Runtime:
                 "addressable (single-process) batch; under multi-process "
                 "sharding use run(), or compact per-host slices before "
                 "assembly")
+        # re-packs pass through the host: a batch sharded over several
+        # devices is put back on its own shardings, not on device 0
+        sharding = getattr(leaf, "sharding", None)
+        shardings = (jax.tree.map(lambda a: a.sharding, state)
+                     if sharding is not None and len(sharding.device_set) > 1
+                     else None)
+
+        def place(host_state):
+            if shardings is None:
+                return jax.tree.map(jnp.asarray, host_state)
+            return jax.tree.map(jax.device_put, host_state, shardings)
+
         runner = self._run_chunk[False]
         B = int(np.asarray(state.halted).shape[0])
         orig_idx = np.arange(B)
@@ -703,7 +717,7 @@ class Runtime:
                     host = owned_host_copy(state)
                     stash.append((orig_idx[drop],
                                   jax.tree.map(lambda a: a[drop], host)))
-                    state = jax.tree.map(lambda a: jnp.asarray(a[keep]), host)
+                    state = place(jax.tree.map(lambda a: a[keep], host))
                     orig_idx = orig_idx[keep]
                     repacks += 1
                     stashed_total += len(drop)
@@ -732,9 +746,9 @@ class Runtime:
         inv = np.argsort(order)
 
         def merge(*leaves):
-            return jnp.asarray(np.concatenate(leaves, axis=0)[inv])
+            return np.concatenate(leaves, axis=0)[inv]
 
-        return jax.tree.map(merge, *[p[1] for p in parts])
+        return place(jax.tree.map(merge, *[p[1] for p in parts]))
 
     def run_single(self, seed: int, max_steps: int, chunk: int = 512,
                    collect_events: bool = True):

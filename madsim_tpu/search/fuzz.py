@@ -44,6 +44,7 @@ import time
 import jax
 import numpy as np
 
+from ..compile.persistent import enable_persistent_cache
 from ..parallel import stats
 from .corpus import Corpus, YIELD_NAMES
 from .mutate import N_MUT_OPS, OP_NAMES, KnobPlan
@@ -181,6 +182,7 @@ def fuzz(rt, max_steps: int, batch: int = 512, max_rounds: int = 16,
       targeted          (ldfi runs only) {supports, truncated_supports,
                         lanes_run, admitted} — the lineage arm's ledger
     """
+    enable_persistent_cache()
     plan = KnobPlan.from_runtime(rt, dup_slots=dup_slots)
     pool = None
     targeted_total = 0

@@ -71,19 +71,53 @@ def worker_cmd(corpus_dir: str, worker_id: int, factory: str, *,
     return cmd
 
 
+def _accelerator_installed() -> bool:
+    """Whether JAX in a worker could land on an accelerator: a TPU or GPU
+    plugin is installed. Answered without initializing JAX here."""
+    import importlib.util
+    return any(importlib.util.find_spec(m) is not None
+               for m in ("libtpu", "jax_plugins"))
+
+
+def check_one_process_per_chip(workers: int, env: dict | None = None
+                               ) -> None:
+    """Refuse a campaign whose workers would share an accelerator.
+
+    Only one process at a time may hold a host's chips: a second worker
+    would hang on the chip lock, and a parent that already holds them
+    leaves none for any worker. Workers whose env pins
+    JAX_PLATFORMS=cpu run on the host and may be many. Otherwise, on a
+    host with an accelerator plugin, one worker drives every chip
+    through its mesh axis (`shards=`)."""
+    e = env if env is not None else os.environ
+    if set(filter(None, e.get("JAX_PLATFORMS", "").split(","))) == {"cpu"}:
+        return
+    xb = sys.modules.get("jax._src.xla_bridge")
+    held = sorted(p for p in getattr(xb, "_backends", {}) if p != "cpu")
+    if held:
+        raise RuntimeError(
+            f"this process holds the {held} backend, so campaign workers "
+            "would hang on the chip lock: start the campaign from a "
+            "process that has not touched the accelerator, or pin the "
+            "workers to the host with env JAX_PLATFORMS=cpu")
+    if workers > 1 and _accelerator_installed():
+        raise ValueError(
+            f"run_campaign(workers={workers}) would start {workers} JAX "
+            "processes on one accelerator host, and only one process may "
+            "hold its chips: use workers=1 with shards=<chips> "
+            "(search/shard.py) to spread one worker over them, or pin "
+            "host-only workers with env JAX_PLATFORMS=cpu")
+
+
 def spawn_worker(corpus_dir: str, worker_id: int, factory: str,
                  env: dict | None = None, **kw) -> subprocess.Popen:
     """Launch one worker detached from this process's jax runtime. `env`
-    REPLACES the child environment when given (callers that must unpin a
-    TPU platform need removals, not just overrides); default inherits.
-    All workers share the persistent compile cache via
-    JAX_COMPILATION_CACHE_DIR; stdout carries the worker's final result
-    as one JSON line."""
+    REPLACES the child environment when given; default inherits.
+    All workers share one persistent compile cache (compile/persistent:
+    an inherited JAX_COMPILATION_CACHE_DIR, else the checkout's
+    .jax_cache); stdout carries the worker's final result as one JSON
+    line."""
     e = dict(env) if env is not None else dict(os.environ)
-    # workers share the campaign's compile cache by default (r8): the
-    # first cold worker compiles, the rest replay the executable
-    e.setdefault("JAX_COMPILATION_CACHE_DIR",
-                 os.path.join(os.path.abspath(corpus_dir), ".jax_cache"))
     # a mesh-sharded worker needs its virtual CPU devices before jax
     # initializes — the flag in the child env is the robust path (the
     # worker's in-process fallback only fires when it is absent).
@@ -157,7 +191,9 @@ def run_campaign(factory: str, corpus_dir: str, *, workers: int = 2,
     `shards` > 1 makes every worker a mesh-sharded campaign of that
     width (search/shard.py): the two scale axes compose — processes
     multiply meshes, and all namespaces stay disjoint by the
-    worker_id*shards+s mapping."""
+    worker_id*shards+s mapping. One process per chip: on an accelerator
+    host `workers` > 1 is refused (`check_one_process_per_chip`)."""
+    check_one_process_per_chip(workers, env)
     t0 = time.monotonic()
     procs = {
         w: spawn_worker(corpus_dir, w, factory,

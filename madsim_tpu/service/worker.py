@@ -21,32 +21,18 @@ import os
 import sys
 
 
-def _force_cpu_mesh(n: int, argv=None) -> None:
+def _force_cpu_mesh(n: int) -> None:
     """Grow the host platform to >= n virtual devices for a mesh-sharded
     worker (--shards). A spawner that already put
     xla_force_host_platform_device_count in XLA_FLAGS
-    (service/campaign.py does) wins outright — newer jaxlibs REJECT
-    having both that flag and jax_num_cpu_devices set, so the config
-    option is only tried when the flag is absent. On jaxlibs without
-    jax_num_cpu_devices the flag is the only mechanism, and XLA parses
-    it at library load — long past by the time `-m` has imported the
-    package — so the fallback RE-EXECS this worker once with the flag
-    in its env (idempotent: the re-exec'd process sees the flag and
-    returns here immediately). Harmless on accelerator hosts either
-    way: both knobs only size the HOST (cpu) backend."""
+    (service/campaign.py does) wins outright — jax rejects having both
+    that flag and jax_num_cpu_devices set. Harmless on accelerator hosts:
+    both knobs only size the HOST (cpu) backend."""
     if "xla_force_host_platform_device_count" in os.environ.get(
             "XLA_FLAGS", ""):
         return
     import jax
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={n}").strip()
-        os.execv(sys.executable,
-                 [sys.executable, "-m", "madsim_tpu.service.worker"]
-                 + list(argv if argv is not None else sys.argv[1:]))
+    jax.config.update("jax_num_cpu_devices", n)
 
 
 def resolve_factory(spec: str):
@@ -94,15 +80,13 @@ def main(argv=None) -> int:
         # unconditional on platform: this only sizes the HOST (cpu)
         # backend's virtual device count — inert when an accelerator is
         # the default platform, required when the worker lands on CPU
-        _force_cpu_mesh(args.shards, argv)
+        _force_cpu_mesh(args.shards)
 
-    # all workers of a campaign share one persistent compile cache (r8):
-    # honor an inherited JAX_COMPILATION_CACHE_DIR, else keep it inside
-    # the corpus dir so the campaign is self-contained
+    # all workers of a campaign share one persistent compile cache (r8),
+    # resolved like every other entry point: an inherited
+    # JAX_COMPILATION_CACHE_DIR, else the checkout's .jax_cache
     from ..compile.persistent import enable_persistent_cache
-    enable_persistent_cache(
-        os.environ.get("JAX_COMPILATION_CACHE_DIR")
-        or os.path.join(os.path.abspath(args.corpus_dir), ".jax_cache"))
+    enable_persistent_cache()
 
     factory = resolve_factory(args.factory)
     rt = factory(**json.loads(args.factory_kwargs or "{}"))

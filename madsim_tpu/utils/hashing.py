@@ -12,10 +12,13 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
-FNV_OFFSET = jnp.uint32(2166136261)
-FNV_PRIME = jnp.uint32(16777619)
+# NumPy scalars, not jnp: a jnp constant at import would initialize a JAX
+# backend, and on a chip host the import alone would claim the chip
+FNV_OFFSET = np.uint32(2166136261)
+FNV_PRIME = np.uint32(16777619)
 
 
 def _leaf_words(a: jax.Array) -> jax.Array:

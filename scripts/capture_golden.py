@@ -20,7 +20,13 @@ it gates against:
 
 Re-running a capture after the gated engine change landed would
 overwrite the evidence with whatever the current tree produces — the
-test would then prove nothing.
+test would then prove nothing. The one legitimate re-run is a RE-FREEZE:
+the toolchain moved every digest while the code did not (PR 21: jax
+0.9.0 turned `jax_threefry_partitionable` on, moving every key-derived
+leaf). Check the contract on the tree first — plane off vs every plane
+compiled in but masked, leaf for leaf — then re-run all four captures
+at one tree. An existing golden keeps its leaf set; only the hashes
+move.
 """
 
 import importlib

@@ -19,12 +19,11 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 tier=${1:-fast}
 
-# Persistent compile cache (DESIGN §10): a workspace-local dir shared by
-# both lanes, so a cold CI process reuses warm XLA executables instead of
-# recompiling every structurally-known step program. Content-keyed — it
-# can only skip the compile stage, never change results.
-export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$PWD/.jax_cache}"
-# ... with the SAME 5s floor tests/conftest.py applies (ROADMAP r12/r16:
+# Persistent compile cache (DESIGN §10): JAX_COMPILATION_CACHE_DIR when
+# set, else the checkout's .jax_cache (compile/persistent.py), shared by
+# both lanes, so a cold CI process reuses warm XLA executables. Content-
+# keyed — it can only skip the compile stage, never change results. Use
+# the SAME 5s floor tests/conftest.py applies (ROADMAP r12/r16:
 # small deserialized executables can corrupt on first invocation — the
 # floor keeps them out of the cache). Without this, the bench smokes
 # below run floor-less (jax's default floor is 1s) and re-seed the

@@ -103,7 +103,15 @@ def run_workload(name: str) -> dict:
 
 
 def capture(path: str = GOLDEN_PATH) -> dict:
+    """Freeze this tree's digests into `path`. Where `path` already holds
+    a golden, only the leaves it names are re-hashed: a re-freeze (a new
+    toolchain moving every digest) keeps the era's leaf set, so each
+    test's list of leaves added since stays meaningful."""
     doc = {name: run_workload(name) for name in BUILDERS}
+    if os.path.exists(path):
+        old = load_golden(path)
+        doc = {w: {r: {k: d[k] for k in old[w][r]} for r, d in runs.items()}
+               for w, runs in doc.items()}
     os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(path, "w") as f:
         json.dump(doc, f, indent=1, sort_keys=True)

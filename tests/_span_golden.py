@@ -36,7 +36,24 @@ leaf_digests = _g.leaf_digests
 run_workload = _g.run_workload
 
 
+TRACE_PATH = os.path.join(os.path.dirname(__file__), "data",
+                          "golden_r22_trace.json")
+
+
+def export_span_off_trace(path: str) -> None:
+    """Lane 0 of the frozen pingpong workload (span never on), exported
+    as Chrome-trace JSON: the bytes golden_r22_trace.json holds."""
+    import numpy as np
+    from madsim_tpu.obs.trace import export_chrome_trace
+    rt = BUILDERS["pingpong"]()
+    run = RUNS["pingpong"]
+    st, _ = rt.run(rt.init_batch(np.arange(run["seeds"], dtype=np.uint32)),
+                   run["max_steps"], run["chunk"])
+    export_chrome_trace(path, state=st, lane=0)
+
+
 def capture(path: str = GOLDEN_PATH) -> dict:
+    export_span_off_trace(TRACE_PATH)
     return _g.capture(path)
 
 

@@ -10,6 +10,8 @@ fingerprints, ring columns). Anything less would make the cache a replay
 domain, which DESIGN §4 forbids.
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -315,29 +317,58 @@ class TestWarmCacheCompacting:
 
 
 class TestPersistentCacheWiring:
-    def test_enable_persistent_cache(self, tmp_path, monkeypatch):
-        import jax
-        from madsim_tpu.compile.persistent import enable_persistent_cache
-        prior = jax.config.jax_compilation_cache_dir
-        try:
-            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-            d = str(tmp_path / "cc")
-            assert enable_persistent_cache(d) == d
-            assert jax.config.jax_compilation_cache_dir == d
-            # env-var path (what scripts/ci.sh exports)
-            d2 = str(tmp_path / "cc2")
-            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d2)
-            assert enable_persistent_cache() == d2
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prior)
+    """One resolution everywhere (compile/persistent.py): the env var
+    when set, else the checkout's fixed .jax_cache — never None, never a
+    directory that moves between processes."""
 
-    def test_noop_without_config(self, monkeypatch):
+    @pytest.fixture
+    def restore_cache_dir(self):
+        import jax
+        prior = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", prior)
+
+    def test_env_var_wins(self, tmp_path, monkeypatch, restore_cache_dir):
         import jax
         from madsim_tpu.compile.persistent import enable_persistent_cache
-        prior = jax.config.jax_compilation_cache_dir
+        d = str(tmp_path / "cc")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+        assert enable_persistent_cache() == d
+        assert jax.config.jax_compilation_cache_dir == d
+
+    def test_default_is_the_checkout(self, monkeypatch, restore_cache_dir):
+        import tempfile
+
+        import jax
+        from madsim_tpu.compile.persistent import enable_persistent_cache
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-        assert enable_persistent_cache() is None
-        assert jax.config.jax_compilation_cache_dir == prior
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        d = enable_persistent_cache()
+        assert d == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == d
+        assert not d.startswith(tempfile.gettempdir())
+
+    def test_campaign_worker_resolves_the_same(self, tmp_path):
+        # a worker spawned the way run_campaign spawns it, on a fresh
+        # temp corpus dir, lands on the checkout cache (its factory
+        # reports the cache dir the worker configured, then exits)
+        from madsim_tpu.service.campaign import spawn_worker
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        (tmp_path / "probe_factory.py").write_text(
+            "import sys\n"
+            "def make():\n"
+            "    import jax\n"
+            "    print(jax.config.jax_compilation_cache_dir)\n"
+            "    sys.exit(0)\n")
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        env.update(JAX_PLATFORMS="cpu",
+                   PYTHONPATH=os.pathsep.join([str(tmp_path), root]))
+        p = spawn_worker(str(tmp_path / "corpus"), 0, "probe_factory:make",
+                         env=env, max_steps=1)
+        out, _ = p.communicate(timeout=120)
+        assert p.returncode == 0
+        assert out.strip() == os.path.join(root, ".jax_cache")
 
 
 @pytest.mark.slow

@@ -72,3 +72,19 @@ class TestShardedEngine:
         assert not bool(state.crashed.any())
         np.testing.assert_array_equal(_fps(rt_oh, plain),
                                       _fps(rt_sc, state))
+
+    def test_compacting_keeps_the_mesh(self):
+        # run_compacting re-packs lanes through the host; a sharded batch
+        # must come back on its mesh (not on device 0), bit-identical
+        import io
+        from madsim_tpu.obs import JsonlObserver
+        rt = _rt()
+        mesh = seed_mesh(jax.devices()[:4])
+        plain, _ = rt.run(rt.init_batch(np.arange(B)), 4000, chunk=16)
+        obs = JsonlObserver(io.StringIO())
+        state = rt.run_compacting(
+            shard_batch(rt.init_batch(np.arange(B)), mesh), 4000, chunk=16,
+            compact_when=0.3, min_batch=8, observer=obs)
+        assert any(r["kind"] == "compact" for r in obs.records)
+        assert len(state.now.sharding.device_set) == 4
+        np.testing.assert_array_equal(_fps(rt, plain), _fps(rt, state))

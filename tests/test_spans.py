@@ -354,18 +354,12 @@ class TestRollups:
 
     def test_span_off_trace_is_byte_identical_to_r22(self, tmp_path):
         # the frozen pingpong golden workload (span never on), exported
-        # at r22 HEAD into data/golden_r22_trace.json: the r23 export
-        # path must reproduce it byte for byte
-        rt = golden.BUILDERS["pingpong"]()
-        run = golden.RUNS["pingpong"]
-        st, _ = rt.run(
-            rt.init_batch(np.arange(run["seeds"], dtype=np.uint32)),
-            run["max_steps"], run["chunk"])
+        # into data/golden_r22_trace.json (frozen at r22, re-frozen at
+        # PR 21 under jax 0.9.0): the export path must reproduce it byte
+        # for byte
         p = str(tmp_path / "pp.json")
-        export_chrome_trace(p, state=st, lane=0)
-        gold = os.path.join(os.path.dirname(__file__), "data",
-                            "golden_r22_trace.json")
-        with open(p, "rb") as a, open(gold, "rb") as g:
+        golden.export_span_off_trace(p)
+        with open(p, "rb") as a, open(golden.TRACE_PATH, "rb") as g:
             assert a.read() == g.read(), \
                 "span-off export must stay byte-identical to r22"
 
