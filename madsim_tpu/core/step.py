@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.scopes import Phases
 from ..ops import select as sel
 from . import prng
 from . import types as T
@@ -132,7 +133,8 @@ def make_step(
                 == jax.tree.structure(spec_default)), \
             "persist mask must match state_spec structure"
 
-    def live_step(s: SimState):
+    def phased_step(s: SimState, ph: Phases):
+        ph.to("step.pick")
         live = ~s.halted  # frozen trajectories no-op via mask gating (the
         # vmap-friendly alternative to freezing with a whole-tree select)
         key, k_sched, k_super, k_handler, k_net = prng.split(s.key, 5)
@@ -333,6 +335,7 @@ def make_step(
         )
 
         # ---- 2. supervisor op (Handle::kill/restart/... as events) ---------
+        ph.to("step.supervisor")
         is_super = valid & (ev_kind == T.EV_SUPER)
         op = jnp.where(is_super, ev_tag, 0)
         ext_keys = prng.split(k_super, 1 + max(len(extensions), 1))
@@ -398,6 +401,7 @@ def make_step(
                 now])                               # [SPAN_WORDS]
 
         # ---- 3. protocol handler dispatch ---------------------------------
+        ph.to("step.handler")
         node_ok = (sel.take1(s.alive, ev_node)
                    & ~sel.take1(s.paused, ev_node))
         is_msg = valid & (ev_kind == T.EV_MSG) & node_ok
@@ -474,6 +478,7 @@ def make_step(
                 t_deadline=jnp.where(hit, T.T_INF, s.t_deadline))
 
         # ---- 4. materialize emissions into the event table ----------------
+        ph.to("step.emit")
         # All emissions are staged into [E]-vectors and written with ONE
         # gather+scatter per table column (slots are distinct by
         # construction), instead of E separate dynamic-index updates — the
@@ -680,6 +685,7 @@ def make_step(
         # touched: trajectories are bit-identical across the knob, and
         # the pf_* columns ride TRACE_FIELDS out of fingerprints.
         if cfg.profile:
+            ph.to("step.profile")
             rec_p = valid & s.pf_on
             act_node = jnp.where(is_super, reset_target, ev_node)
             ohP = sel.row_onehot(cfg.n_nodes, act_node)      # [N]
@@ -726,6 +732,7 @@ def make_step(
         # harness.slo_invariant) sees this dispatch's completion.
         lat_e2e = None
         if cfg.latency_hist > 0:
+            ph.to("step.latency")
             LB = cfg.latency_hist
             rec_l = valid & s.lh_on
             thr = jnp.asarray([1 << j for j in range(LB - 1)], jnp.int32)
@@ -776,6 +783,7 @@ def make_step(
         # owner. No randomness, no non-span state — the pf_*/lh_*
         # transparency contract.
         if cfg.span_attr:
+            ph.to("step.span")
             tail_sp = (is_complete & s.sp_on & (s.slo_target > 0)
                        & (lat_e2e_raw > s.slo_target))
             comp_vals = jnp.stack([jnp.asarray(1, jnp.int32), meas_sq,
@@ -800,6 +808,7 @@ def make_step(
         # one-hot select per step; `every` is a dynamic operand
         # (s.sketch_every), only the slot COUNT shapes the program.
         if cfg.sketch_slots > 0:
+            ph.to("step.sketch")
             period = jnp.maximum(s.sketch_every, 1)
             ck = s.steps // period
             at_ck = (valid & (s.steps == ck * period) & (ck >= 1)
@@ -825,6 +834,7 @@ def make_step(
         # end-condition checks so an `invariant=` (e.g.
         # harness.recovery_invariant) sees this dispatch's window.
         if cfg.series_windows > 0:
+            ph.to("step.series")
             SW = cfg.series_windows
             rec_s = valid & s.sr_on
             w_idx = jnp.minimum(now // jnp.maximum(s.window_len, 1),
@@ -901,6 +911,7 @@ def make_step(
                          & done_s).astype(jnp.int32)))
 
         # ---- 5. end conditions -------------------------------------------
+        ph.to("step.check")
         # deadlock: nothing can ever run again (madsim task.rs:116 panic)
         crash = crash | ((~any_ev | time_over) & live)
         crash_code = jnp.where(
@@ -947,6 +958,7 @@ def make_step(
         # One one-hot row write per column, no randomness consumed: all
         # non-trace state stays bit-identical across trace_cap settings.
         if cfg.trace_cap > 0:
+            ph.to("step.ring")
             rec_w = record["fired"] & s.trace_on
             # DYNAMIC capacity (s.trace_cap), bucket-sized columns: the
             # compiled program depends only on cfg.trace_cap_bucket, so
@@ -994,6 +1006,14 @@ def make_step(
                 tr_lamport=ringput(s.tr_lamport, ev_lamport),
                 trace_pos=s.trace_pos + rec_w.astype(jnp.int32),
             )
+        return s, record
+
+    def live_step(s: SimState):
+        # each phase under its own jax.named_scope (obs/scopes.py): op
+        # metadata only, so a device profile sums by phase and the
+        # program stays the same; extension hooks run after the phases
+        with Phases() as ph:
+            s, record = phased_step(s, ph)
         if extensions:
             new_ext = dict(s.ext)
             for e in extensions:

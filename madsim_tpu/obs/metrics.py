@@ -23,7 +23,13 @@ blocked on anyway. Record kinds (each a flat JSON-able dict carrying
            round's median first-divergence slot vs the consensus prefix)
            when the build compiles the prefix sketch in
            (cfg.sketch_slots > 0) — depth telemetry riding the sketch
-           transfer the corpus already pays for. Builds with the SLO
+           transfer the corpus already pays for. Every fuzz_round carries
+           `host_s`: the host seconds spent in each stage of the search
+           loop since the previous record (search.fuzz.STAGES: schedule,
+           mutate, dispatch, wait, fetch, admit, crashes, dedup, record,
+           sync; `wait` is the block on the round's device result, so
+           the round takes about Σ host_s and the other stages are the
+           host's own time), timed by `Stages`. Builds with the SLO
            latency plane compiled in (cfg.latency_hist > 0, r16) add
            `lat_p99` (the round batch's merged end-to-end p99 estimate
            in ticks, bucket-CDF lower bound), `lat_p50`, and `slo_miss`
@@ -58,8 +64,10 @@ silently eats its own bugs measures nothing).
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
+import time
 from typing import IO
 
 
@@ -80,6 +88,35 @@ class SweepObserver:
 
     def on_done(self, rec: dict) -> None:
         pass
+
+
+class Stages:
+    """Host seconds by stage of a loop, kept in memory until `take()`.
+
+    `with stages("admit"):` times the block on the host clock and marks it
+    as `jax.profiler.TraceAnnotation("<prefix>.admit")`, so a profiler
+    trace shows the span in its host plane on the device's clock. Stages
+    do not nest. `take()` returns {stage: seconds} over every name given,
+    0.0 for a stage that did not run, and starts the count again."""
+
+    def __init__(self, prefix: str, names):
+        self.prefix = prefix
+        self.names = tuple(names)
+        self._s = dict.fromkeys(self.names, 0.0)
+
+    @contextlib.contextmanager
+    def __call__(self, name: str):
+        import jax
+        if name not in self._s:
+            raise ValueError(f"unknown stage {name!r} (not in {self.names})")
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation(f"{self.prefix}.{name}"):
+            yield
+        self._s[name] += time.perf_counter() - t0
+
+    def take(self) -> dict[str, float]:
+        out, self._s = self._s, dict.fromkeys(self.names, 0.0)
+        return out
 
 
 class JsonlObserver(SweepObserver):

@@ -432,6 +432,25 @@ class Runtime:
 
         return jax.jit(run, static_argnums=2, donate_argnums=0)
 
+    def fused_op_scopes(self, batch: int, chunk: int = 512) -> dict[str, str]:
+        """Which step phase each instruction of the compiled `run_fused`
+        program belongs to: {instruction name as a device profile shows
+        it (`%fusion.764`): innermost `step.*` phase (obs/scopes.py), or
+        "" for none (the while loops, copies, the loop predicate)}.
+
+        Use it to name the phases in a profile of your own model's
+        `run_fused(init_batch(seeds), steps, chunk)` at `batch` lanes: sum
+        each op's device time under its phase. It compiles the program for
+        the default device from abstract shapes, so nothing runs; where
+        the program was already compiled with the persistent cache on,
+        the compile is a cache hit."""
+        from ..obs.scopes import op_scopes
+        enable_persistent_cache()
+        state = jax.eval_shape(self.init_batch, np.zeros(batch, np.uint32))
+        n_chunks = jax.ShapeDtypeStruct((), jnp.int32)
+        compiled = self._fused_runner.lower(state, n_chunks, chunk).compile()
+        return op_scopes(compiled.as_text())
+
     def run_fused(self, state: SimState, max_steps: int,
                   chunk: int = 512,
                   ckpt_every: int | None = None, ckpt_log=None) -> SimState:

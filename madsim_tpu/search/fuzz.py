@@ -45,6 +45,7 @@ import jax
 import numpy as np
 
 from ..compile.persistent import enable_persistent_cache
+from ..obs.metrics import Stages
 from ..parallel import stats
 from .corpus import Corpus, YIELD_NAMES
 from .mutate import N_MUT_OPS, OP_NAMES, KnobPlan
@@ -56,6 +57,12 @@ from .mutate import N_MUT_OPS, OP_NAMES, KnobPlan
 # strides; the 2^23-worker ID namespace is a separate, wider contract —
 # shard bigger fleets across base_seeds).
 WORKER_SEED_STRIDE = 1 << 26
+
+# the search loop's host stages, in the order a round meets them; each
+# `fuzz_round` record carries the seconds spent in each since the last
+# record (`host_s`), each a `madsim.fuzz.<stage>` profiler span
+STAGES = ("schedule", "mutate", "dispatch", "wait", "fetch", "admit",
+          "crashes", "dedup", "record", "sync")
 
 
 def _lat_fields(lat_brief: dict) -> dict:
@@ -165,7 +172,8 @@ def fuzz(rt, max_steps: int, batch: int = 512, max_rounds: int = 16,
     ldfi=None is the pre-r22 fuzzer bit for bit, stores included.
 
     observer: obs.metrics.SweepObserver — `on_round` records of kind
-    "fuzz_round" (explore's round schema + corpus_size/new_crash_codes),
+    "fuzz_round" (explore's round schema + corpus_size/new_crash_codes,
+    and `host_s`: host seconds by STAGES since the previous record),
     `on_done` with the final result; hooks ride the harvest the loop
     already blocks on.
 
@@ -280,6 +288,7 @@ def fuzz(rt, max_steps: int, batch: int = 512, max_rounds: int = 16,
         if burst_bonus is not None:
             corpus.burst_bonus = float(burst_bonus)
     master = jax.random.PRNGKey(np.uint32(rng_seed ^ 0x5EED5EED))
+    stages = Stages("madsim.fuzz", STAGES)
 
     def launch(r):
         """Schedule + mutate + dispatch one round without blocking on
@@ -292,56 +301,63 @@ def fuzz(rt, max_steps: int, batch: int = 512, max_rounds: int = 16,
                  + np.uint64(lane0)).astype(np.uint32)
         targeted = np.zeros(batch, bool)
         if r == 0 or len(corpus) == 0:
-            knobs_dev = {k: v for k, v in plan.base_batch(batch).items()}
+            with stages("mutate"):
+                knobs_dev = {k: v for k, v in plan.base_batch(batch).items()}
             ids = np.full(batch, -1, np.int64)
             hist = None
             last_op = np.full(batch, -1, np.int64)
         else:
-            parents, ids = corpus.schedule(batch)
-            key = jax.random.fold_in(master, np.uint32(r))
-            tvecs, tseeds = [], []
-            if pool is not None and len(pool):
-                tvecs, tseeds = synthesize(
-                    plan, pool, min(batch, max(1, int(batch * ldfi.frac))),
-                    max_cuts=ldfi.max_cuts, lead=ldfi.lead,
-                    rank_cap=ldfi.rank_cap, with_seeds=True)
-            if tvecs:
-                # the lineage arm: targeted vectors ride the LAST T
-                # lanes. The masked mutate (the shard driver's kernel —
-                # module-level jit, traced once per shape) leaves those
-                # lanes' parents untouched so the havoc histogram and
-                # last-op attribution count ONLY real mutants; the
-                # synthesized rows then overwrite them host-side and
-                # plan.apply bounds-checks them like any mutant — zero
-                # new compiled programs for a targeted round
-                tn = len(tvecs)
-                mask = np.ones(batch, bool)
-                mask[batch - tn:] = False
-                knobs_dev, hist, last_op = plan.mutate_masked(
-                    parents, key, mask, havoc=havoc)
-                knobs_host = {k: np.asarray(v).copy()
-                              for k, v in knobs_dev.items()}
-                tb = KnobPlan.stack(tvecs)
-                for k in knobs_host:
-                    knobs_host[k][batch - tn:] = tb[k]
-                knobs_dev = knobs_host
-                ids = ids.copy()
-                ids[batch - tn:] = -1     # no havoc parent to reward
-                targeted[batch - tn:] = True
-                # pin each targeted lane to the green seed its cut was
-                # aimed at: edge instants are seed-specific, so the cut
-                # only lands inside the trajectory it was extracted from
-                for j, ts_seed in enumerate(tseeds):
-                    if ts_seed is not None:
-                        seeds[batch - tn + j] = np.uint32(ts_seed)
+            with stages("schedule"):
+                parents, ids = corpus.schedule(batch)
+                tvecs, tseeds = [], []
+                if pool is not None and len(pool):
+                    tvecs, tseeds = synthesize(
+                        plan, pool,
+                        min(batch, max(1, int(batch * ldfi.frac))),
+                        max_cuts=ldfi.max_cuts, lead=ldfi.lead,
+                        rank_cap=ldfi.rank_cap, with_seeds=True)
+            with stages("mutate"):
+                key = jax.random.fold_in(master, np.uint32(r))
+                if tvecs:
+                    # the lineage arm: targeted vectors ride the LAST T
+                    # lanes. The masked mutate (search/shard.py's
+                    # kernel — module-level jit, traced once per shape)
+                    # leaves those lanes' parents untouched so the havoc
+                    # histogram and last-op attribution count ONLY real
+                    # mutants; the synthesized rows then overwrite them
+                    # host-side and plan.apply bounds-checks them like
+                    # any mutant — zero new compiled programs for a
+                    # targeted round
+                    tn = len(tvecs)
+                    mask = np.ones(batch, bool)
+                    mask[batch - tn:] = False
+                    knobs_dev, hist, last_op = plan.mutate_masked(
+                        parents, key, mask, havoc=havoc)
+                    knobs_host = {k: np.asarray(v).copy()
+                                  for k, v in knobs_dev.items()}
+                    tb = KnobPlan.stack(tvecs)
+                    for k in knobs_host:
+                        knobs_host[k][batch - tn:] = tb[k]
+                    knobs_dev = knobs_host
+                    ids = ids.copy()
+                    ids[batch - tn:] = -1     # no havoc parent to reward
+                    targeted[batch - tn:] = True
+                    # pin each targeted lane to the green seed its cut
+                    # was aimed at: edge instants are seed-specific, so
+                    # the cut only lands inside the trajectory it was
+                    # extracted from
+                    for j, ts_seed in enumerate(tseeds):
+                        if ts_seed is not None:
+                            seeds[batch - tn + j] = np.uint32(ts_seed)
+                else:
+                    knobs_dev, hist, last_op = plan.mutate(parents, key,
+                                                           havoc=havoc)
+        with stages("dispatch"):
+            state = plan.apply(rt.init_batch(seeds), knobs_dev)
+            if fused:
+                state = rt.run_fused(state, max_steps, chunk)
             else:
-                knobs_dev, hist, last_op = plan.mutate(parents, key,
-                                                       havoc=havoc)
-        state = plan.apply(rt.init_batch(seeds), knobs_dev)
-        if fused:
-            state = rt.run_fused(state, max_steps, chunk)
-        else:
-            state, _ = rt.run(state, max_steps, chunk)
+                state, _ = rt.run(state, max_steps, chunk)
         return seeds, ids, knobs_dev, hist, last_op, targeted, state
 
     def harvest(launched):
@@ -351,29 +367,34 @@ def fuzz(rt, max_steps: int, batch: int = 512, max_rounds: int = 16,
         the build compiles the prefix sketch in, the [B, S] sketch
         batch (also kilobytes — the divergence-depth signal)."""
         seeds, ids, knobs_dev, hist, last_op, targeted, state = launched
-        knobs_host = {k: np.asarray(v) for k, v in knobs_dev.items()}
-        hashes = stats.sched_hash_u64(state)
-        sk = np.asarray(state.cov_sketch)
-        sketches = sk if sk.ndim == 2 and sk.shape[1] > 0 else None
-        # tail-latency signal (r16): per-lane e2e p99 for corpus energy
-        # + the round's merged brief for telemetry — None on builds
-        # without the latency plane (one [B] + one O(buckets)
-        # transfer); the brief only when something will consume it
-        lat_p99 = stats.lane_e2e_p99(state)
-        lat_brief = (stats.latency_brief(state)
-                     if lat_p99 is not None
-                     and (observer is not None or store is not None)
-                     else None)
-        # transient-spike signal (r21): per-lane deepest per-window
-        # spike for corpus energy — None on builds without the series
-        # plane (one [B] transfer)
-        burst = stats.lane_burst(state)
-        if hist is not None:
-            op_hist[:] += np.asarray(hist)
-        return (seeds, ids, knobs_host, hashes,
-                np.asarray(state.crashed), np.asarray(state.crash_code),
-                hist is not None, np.asarray(last_op), sketches, state,
-                lat_p99, lat_brief, burst, targeted)
+        with stages("wait"):
+            # the round's device work: waiting here, not in the transfers
+            # below, splits device time from the host's own
+            state.sched_hash.block_until_ready()
+        with stages("fetch"):
+            knobs_host = {k: np.asarray(v) for k, v in knobs_dev.items()}
+            hashes = stats.sched_hash_u64(state)
+            sk = np.asarray(state.cov_sketch)
+            sketches = sk if sk.ndim == 2 and sk.shape[1] > 0 else None
+            # tail-latency signal (r16): per-lane e2e p99 for corpus energy
+            # + the round's merged brief for telemetry — None on builds
+            # without the latency plane (one [B] + one O(buckets)
+            # transfer); the brief only when something will consume it
+            lat_p99 = stats.lane_e2e_p99(state)
+            lat_brief = (stats.latency_brief(state)
+                         if lat_p99 is not None
+                         and (observer is not None or store is not None)
+                         else None)
+            # transient-spike signal (r21): per-lane deepest per-window
+            # spike for corpus energy — None on builds without the series
+            # plane (one [B] transfer)
+            burst = stats.lane_burst(state)
+            if hist is not None:
+                op_hist[:] += np.asarray(hist)
+            return (seeds, ids, knobs_host, hashes,
+                    np.asarray(state.crashed), np.asarray(state.crash_code),
+                    hist is not None, np.asarray(last_op), sketches, state,
+                    lat_p99, lat_brief, burst, targeted)
 
     def verified(harvested):
         """The run-twice resume guard (verify_resume): re-dispatch the
@@ -439,144 +460,153 @@ def fuzz(rt, max_steps: int, batch: int = 512, max_rounds: int = 16,
          last_op, sketches, state, lat_p99, lat_brief, burst,
          targeted) = harvested
         rounds += 1
-        cstats = corpus.observe(knobs_host, seeds, hashes, crashed, codes,
-                                ids, r, sketches=sketches,
-                                last_op=last_op, lat_p99=lat_p99,
-                                burst=burst,
-                                origin=targeted if ldfi is not None
-                                else None)
-        yield_hist[:] += cstats["op_yield"]
-        if ldfi is not None:
-            targeted_total += int(targeted.sum())
-            targeted_yield_total += int(cstats.get("targeted_yield", 0))
-            if len(pool) < ldfi.lanes:
-                # harvest green supports: UNMUTATED lanes (bootstrap or
-                # havoc no-ops; last_op < 0, not targeted) that did not
-                # crash — the undisturbed trajectories whose success
-                # support is worth cutting. Bounded: the pool stops
-                # growing at ldfi.lanes supports, so the per-lane host
-                # walks are a one-time cost, not a per-round tax
-                for i in range(len(seeds)):
-                    if len(pool) >= ldfi.lanes:
-                        break
-                    if (bool(crashed[i]) or int(last_op[i]) >= 0
-                            or bool(targeted[i])):
-                        continue
-                    sup = extract_support(
-                        state, int(i), witness=ldfi.witness,
-                        replay=ldfi.replay, rt=rt, seed=int(seeds[i]),
-                        knobs=KnobPlan.lane(knobs_host, int(i)))
-                    if sup is not None:
-                        pool.add(sup, seed=int(seeds[i]))
-        for i in np.nonzero(crashed)[0]:
-            c = int(codes[i])
-            if not mutated:     # seed-alone handles: bootstrap lanes only
-                crashes.setdefault(c, int(seeds[i]))
-            if c not in repros:
-                kn = KnobPlan.lane(knobs_host, int(i))
-                repros[c] = dict(seed=int(seeds[i]), round=r, knobs=kn,
-                                 script=plan.to_scenario(kn).describe())
-        if buckets is not None and crashed.any():
-            # dedup crashes into causal-fingerprint buckets: one
-            # representative lane per distinct (crash code, origin) per
-            # round keeps the host-side explain work bounded (the chain
-            # walk is O(trace_cap) per lane; codes, not lanes, are the
-            # cheap first partition — the fingerprint then splits bugs
-            # sharing a code across rounds). The origin axis matters:
-            # targeted lanes ride the batch TAIL, so a code-only dedup
-            # would always hand representation to an earlier havoc lane
-            # and the targeted arm could never open a bucket it earned
-            coded: set[tuple] = set()
+        with stages("admit"):
+            cstats = corpus.observe(knobs_host, seeds, hashes, crashed, codes,
+                                    ids, r, sketches=sketches,
+                                    last_op=last_op, lat_p99=lat_p99,
+                                    burst=burst,
+                                    origin=targeted if ldfi is not None
+                                    else None)
+            yield_hist[:] += cstats["op_yield"]
+            if ldfi is not None:
+                targeted_total += int(targeted.sum())
+                targeted_yield_total += int(cstats.get("targeted_yield", 0))
+                if len(pool) < ldfi.lanes:
+                    # harvest green supports: UNMUTATED lanes (bootstrap or
+                    # havoc no-ops; last_op < 0, not targeted) that did not
+                    # crash — the undisturbed trajectories whose success
+                    # support is worth cutting. Bounded: the pool stops
+                    # growing at ldfi.lanes supports, so the per-lane host
+                    # walks are a one-time cost, not a per-round tax
+                    for i in range(len(seeds)):
+                        if len(pool) >= ldfi.lanes:
+                            break
+                        if (bool(crashed[i]) or int(last_op[i]) >= 0
+                                or bool(targeted[i])):
+                            continue
+                        sup = extract_support(
+                            state, int(i), witness=ldfi.witness,
+                            replay=ldfi.replay, rt=rt, seed=int(seeds[i]),
+                            knobs=KnobPlan.lane(knobs_host, int(i)))
+                        if sup is not None:
+                            pool.add(sup, seed=int(seeds[i]))
+        with stages("crashes"):
             for i in np.nonzero(crashed)[0]:
-                c = (int(codes[i]),
-                     bool(targeted[int(i)]) if ldfi is not None else False)
-                if c in coded:
-                    continue
-                coded.add(c)
-                key, opened = buckets.observe_lane(
-                    state, int(i), seed=int(seeds[i]),
-                    knobs=KnobPlan.lane(knobs_host, int(i)),
-                    round_no=r, worker_id=worker_id,
-                    last_op=int(last_op[int(i)]),
-                    origin=(("targeted" if targeted[int(i)] else "havoc")
-                            if ldfi is not None else None))
-                if opened:
-                    opened_buckets.append(key)
-        n_crashed += int(crashed.sum())
-        fresh = set(hashes.tolist()) - seen
-        seen |= fresh
+                c = int(codes[i])
+                if not mutated:     # seed-alone handles: bootstrap lanes only
+                    crashes.setdefault(c, int(seeds[i]))
+                if c not in repros:
+                    kn = KnobPlan.lane(knobs_host, int(i))
+                    repros[c] = dict(seed=int(seeds[i]), round=r, knobs=kn,
+                                     script=plan.to_scenario(kn).describe())
+            if buckets is not None and crashed.any():
+                # dedup crashes into causal-fingerprint buckets: one
+                # representative lane per distinct (crash code, origin) per
+                # round keeps the host-side explain work bounded (the chain
+                # walk is O(trace_cap) per lane; codes, not lanes, are the
+                # cheap first partition — the fingerprint then splits bugs
+                # sharing a code across rounds). The origin axis matters:
+                # targeted lanes ride the batch TAIL, so a code-only dedup
+                # would always hand representation to an earlier havoc lane
+                # and the targeted arm could never open a bucket it earned
+                coded: set[tuple] = set()
+                for i in np.nonzero(crashed)[0]:
+                    c = (int(codes[i]),
+                         bool(targeted[int(i)]) if ldfi is not None else False)
+                    if c in coded:
+                        continue
+                    coded.add(c)
+                    key, opened = buckets.observe_lane(
+                        state, int(i), seed=int(seeds[i]),
+                        knobs=KnobPlan.lane(knobs_host, int(i)),
+                        round_no=r, worker_id=worker_id,
+                        last_op=int(last_op[int(i)]),
+                        origin=(("targeted" if targeted[int(i)] else "havoc")
+                                if ldfi is not None else None))
+                    if opened:
+                        opened_buckets.append(key)
+            n_crashed += int(crashed.sum())
+        with stages("dedup"):
+            fresh = set(hashes.tolist()) - seen
+            seen |= fresh
         new_per_round.append(len(fresh))
         dry = dry + 1 if not fresh else 0
         if observer is not None:
-            rec = dict(
-                kind="fuzz_round", round=rounds, batch=batch,
-                seeds_run=rounds * batch, new_schedules=len(fresh),
-                distinct_total=len(seen), crashes=n_crashed,
-                corpus_size=cstats["size"],
-                new_crash_codes=cstats["new_crash_codes"],
-                # coverage-yield attribution (r15): the round's
-                # admissions credited to the operator that produced
-                # each admitted mutant (sums to `admitted`; "base" =
-                # untouched lanes), plus where the corpus's mutation
-                # budget sits — the fuzzer-effectiveness half of the
-                # profiler plane
-                admitted=cstats["new"],
-                op_yield={YIELD_NAMES[i]: int(cstats["op_yield"][i])
-                          for i in range(len(YIELD_NAMES))},
-                corpus_energy=corpus.energy_summary(),
-                dry_rounds=dry, wall_s=time.perf_counter() - t0)
-            if ldfi is not None:
-                # the lineage arm's round ledger: lanes given to
-                # targeted vectors, their admissions (the slice of
-                # `admitted` that was aimed, not sprayed), and the
-                # support pool's size/honesty
-                rec.update(targeted=int(targeted.sum()),
-                           targeted_yield=int(
-                               cstats.get("targeted_yield", 0)),
-                           support_pool=len(pool))
-            if lat_brief is not None:
-                # the round's tail (obs/metrics.py schema): merged e2e
-                # p50/p99 estimates + SLO misses for this round's batch
-                rec.update(_lat_fields(lat_brief))
-            if buckets is not None:
-                rec["buckets_opened"] = len(opened_buckets)
-            if sketches is not None:
-                # divergence depth of this round's mutants (median
-                # first-divergence slot vs the consensus prefix): how
-                # early the round's schedule rewiring bit, off the
-                # sketch transfer the corpus already paid for
-                rec["div_slot_p50"] = int(np.median(
-                    stats.first_divergence_slots(sketches)))
+            with stages("record"):
+                rec = dict(
+                    kind="fuzz_round", round=rounds, batch=batch,
+                    seeds_run=rounds * batch, new_schedules=len(fresh),
+                    distinct_total=len(seen), crashes=n_crashed,
+                    corpus_size=cstats["size"],
+                    new_crash_codes=cstats["new_crash_codes"],
+                    # coverage-yield attribution (r15): the round's
+                    # admissions credited to the operator that produced
+                    # each admitted mutant (sums to `admitted`; "base" =
+                    # untouched lanes), plus where the corpus's mutation
+                    # budget sits — the fuzzer-effectiveness half of the
+                    # profiler plane
+                    admitted=cstats["new"],
+                    op_yield={YIELD_NAMES[i]: int(cstats["op_yield"][i])
+                              for i in range(len(YIELD_NAMES))},
+                    corpus_energy=corpus.energy_summary(),
+                    dry_rounds=dry)
+                if ldfi is not None:
+                    # the lineage arm's round ledger: lanes given to
+                    # targeted vectors, their admissions (the slice of
+                    # `admitted` that was aimed, not sprayed), and the
+                    # support pool's size/honesty
+                    rec.update(targeted=int(targeted.sum()),
+                               targeted_yield=int(
+                                   cstats.get("targeted_yield", 0)),
+                               support_pool=len(pool))
+                if lat_brief is not None:
+                    # the round's tail (obs/metrics.py schema): merged e2e
+                    # p50/p99 estimates + SLO misses for this round's batch
+                    rec.update(_lat_fields(lat_brief))
+                if buckets is not None:
+                    rec["buckets_opened"] = len(opened_buckets)
+                if sketches is not None:
+                    # divergence depth of this round's mutants (median
+                    # first-divergence slot vs the consensus prefix): how
+                    # early the round's schedule rewiring bit, off the
+                    # sketch transfer the corpus already paid for
+                    rec["div_slot_p50"] = int(np.median(
+                        stats.first_divergence_slots(sketches)))
+            # take the stages and the clock at one point, so host_s
+            # and the gap between two records' wall_s cover one span
+            rec.update(host_s=stages.take(),
+                       wall_s=time.perf_counter() - t0)
             observer.on_round(rec)
         if store is not None and (
                 (r + 1 - round_start) % sync_every == 0
                 or dry >= dry_rounds or r + 1 == max_rounds):
-            # the durability point: after observe/buckets, BEFORE the
-            # next round's schedule draw — a resume restores the rng
-            # state saved here and replays that draw identically.
-            # The campaign-timeline row goes FIRST: a kill between the
-            # two re-runs the round and re-appends an identical row
-            # (deduped by rounds_done in campaign_timeline), so the
-            # durable timeline has no gaps and no double counts
-            wall_now = wall_prior + time.perf_counter() - t0
-            mrow = dict(
-                t=time.time(), worker=worker_id, rounds_done=r + 1,
-                coverage=len(seen), seeds_run=(r + 1) * batch,
-                crashes=n_crashed, corpus_size=len(corpus),
-                dry=dry, wall_s=round(wall_now, 3),
-                op_yield=[int(x) for x in yield_hist])
-            if ldfi is not None:
-                mrow["targeted_yield"] = targeted_yield_total
-            if lat_brief is not None:
-                # the durable p99 timeline (campaign_report folds the
-                # rows into a p99_curve): this sync's round-batch tail
-                mrow.update(_lat_fields(lat_brief))
-            store.append_metrics(worker_id, mrow)
-            store.sync(corpus, worker_id, rounds_done=r + 1, dry=dry,
-                       op_hist=op_hist, op_yield=yield_hist,
-                       wall_s=wall_now,
-                       targeted_yield=(targeted_yield_total
-                                       if ldfi is not None else None))
+            with stages("sync"):
+                # the durability point: after observe/buckets, BEFORE the
+                # next round's schedule draw — a resume restores the rng
+                # state saved here and replays that draw identically.
+                # The campaign-timeline row goes FIRST: a kill between the
+                # two re-runs the round and re-appends an identical row
+                # (deduped by rounds_done in campaign_timeline), so the
+                # durable timeline has no gaps and no double counts
+                wall_now = wall_prior + time.perf_counter() - t0
+                mrow = dict(
+                    t=time.time(), worker=worker_id, rounds_done=r + 1,
+                    coverage=len(seen), seeds_run=(r + 1) * batch,
+                    crashes=n_crashed, corpus_size=len(corpus),
+                    dry=dry, wall_s=round(wall_now, 3),
+                    op_yield=[int(x) for x in yield_hist])
+                if ldfi is not None:
+                    mrow["targeted_yield"] = targeted_yield_total
+                if lat_brief is not None:
+                    # the durable p99 timeline (campaign_report folds the
+                    # rows into a p99_curve): this sync's round-batch tail
+                    mrow.update(_lat_fields(lat_brief))
+                store.append_metrics(worker_id, mrow)
+                store.sync(corpus, worker_id, rounds_done=r + 1, dry=dry,
+                           op_hist=op_hist, op_yield=yield_hist,
+                           wall_s=wall_now,
+                           targeted_yield=(targeted_yield_total
+                                           if ldfi is not None else None))
         if dry >= dry_rounds:
             break
         pending = nxt if nxt is not None else (

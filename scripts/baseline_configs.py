@@ -152,8 +152,8 @@ def config4(scale):
     Every chunk's client histories run through the linearizability
     checker (native C++, Python fallback beyond 57 ops/key).
 
-    Shapes are right-sized from the r5 ablation (scripts/profile_config4.py,
-    CONFIG4_PROFILE_r05.json): log_capacity 48->32 and event_capacity
+    Shapes are right-sized from the r5 ablation (DESIGN.md "Where config
+    4's 8x went"): log_capacity 48->32 and event_capacity
     128->96 measured 2.0x per-event on CPU at identical workload semantics
     (same nodes/ops/chaos/checker; 32 >= the 22-entry no-compaction floor
     asserted by make_kv_runtime, and any overflow crashes loudly via oops).

@@ -94,3 +94,9 @@ def test_fused_runner_shards_over_four_chips(topo, no_persistent_cache,
     hlo = compiled.as_text()
     # lanes never talk: the only collective is the halt test's all-reduce
     assert "all-gather" not in hlo and "all-to-all" not in hlo
+    # the step's phase scopes survive the TPU compiler's passes as op
+    # metadata, so a chip profile's ops map back to their phases
+    from madsim_tpu.obs.scopes import op_scopes
+    assert set(op_scopes(hlo).values()) == {
+        "", "step.pick", "step.supervisor", "step.handler", "step.emit",
+        "step.check"}
