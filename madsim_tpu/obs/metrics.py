@@ -17,7 +17,9 @@ blocked on anyway. Record kinds (each a flat JSON-able dict carrying
            `op_yield` — the round's admissions attributed to the havoc
            operator that produced each admitted mutant ("base" =
            untouched lanes; the per-operator counts sum to `admitted`)
-           — and `corpus_energy` (the scheduler's energy distribution:
+           — `evicted` (the admissions that replaced the coldest slot of
+           a full corpus), and `corpus_energy` (the scheduler's energy
+           distribution:
            entries/total/mean/p50/p90/max/crash_entries), plus
            div_slot_p50 (the
            round's median first-divergence slot vs the consensus prefix)

@@ -5,8 +5,12 @@ vectors whose lane produced a `sched_hash` never seen before — the corpus
 is KEYED AND DEDUPED by the coverage digest itself (one entry per distinct
 u64 schedule hash), so it can only grow when coverage grows. Host-side and
 numpy-only: the corpus is bookkeeping between device rounds, sized in
-kilobytes, and never on the hot path (corpus work overlaps device compute
-in the pipelined fuzz loop exactly like explore()'s dedup).
+kilobytes. It IS on the fuzz loop's critical path: `observe` runs once a
+round over every lane, and the pipelined loop only hides it while it is
+shorter than the device's round. With a full corpus nearly every
+admission evicts, so `observe` keeps the round's energies in one float64
+array (decay, coldest-slot argmin, parent rewards) instead of scanning
+the entry dicts per admission, and writes them back on return.
 
 Energy rules (the AFL-style scheduler, simplified to what the batched
 setting needs):
@@ -201,15 +205,26 @@ class Corpus:
         return True
 
     def _insert(self, entry: dict) -> None:
-        self._by_id[entry["id"]] = entry
-        if len(self.entries) < self.max_entries:
-            self.entries.append(entry)
-        else:                        # replace the coldest slot
+        """Place an entry merged or loaded outside `observe`: append while
+        there is room, else replace the coldest slot by the dicts'
+        energies (first index on ties)."""
+        j = len(self.entries)
+        if j >= self.max_entries:
             j = int(np.argmin([e["energy"] for e in self.entries]))
-            del self._by_id[self.entries[j]["id"]]
-            if self.track_evictions:
-                self.evicted_unsynced.append(self.entries[j])
-            self.entries[j] = entry
+        self._place(entry, j)
+
+    def _place(self, entry: dict, j: int) -> None:
+        """Put `entry` in slot `j` (== len: append), evicting the
+        occupant."""
+        self._by_id[entry["id"]] = entry
+        if j == len(self.entries):
+            self.entries.append(entry)
+            return
+        old = self.entries[j]
+        del self._by_id[old["id"]]
+        if self.track_evictions:
+            self.evicted_unsynced.append(old)
+        self.entries[j] = entry
 
     # ------------------------------------------------------------------
     def energy_summary(self) -> dict:
@@ -257,11 +272,14 @@ class Corpus:
         gain `targeted_yield`, targeted admissions counted the same way
         op_yield's "base" slot counts them (a targeted lane's last_op
         is -1). Returns
-        admission stats; with `last_op` given they include `op_yield` —
+        admission stats: `new` admissions, `evicted` (those of them that
+        replaced a slot of a full corpus), `size`, `new_crash_codes`;
+        with `last_op` given they include `op_yield` —
         admissions attributed by operator (int64[N_MUT_OPS + 1], last
         slot = "base"), summing exactly to `new`: which operators'
         mutants actually bought coverage, not just which ran."""
         new = 0
+        evicted = 0
         new_crash_codes = []
         targeted_yield = 0
         op_yield = (np.zeros(N_MUT_OPS + 1, np.int64)
@@ -298,8 +316,15 @@ class Corpus:
                 # burst-amplification bonus scale: each lane's deepest
                 # per-window spike relative to the round's worst, [0, 1]
                 burst_rel = bp / burst_max
-        for e in self.entries:
-            e["energy"] = max(0.05, e["energy"] * self.decay)
+        # the round's energies live in `en` (slot order) until the
+        # write-back below; float64 keeps every value the dicts' Python
+        # floats would hold, and argmin breaks ties to the first slot as
+        # the list scan of `_insert` does. `slot_of` follows `_by_id`.
+        n = len(self.entries)
+        en = np.empty(max(self.max_entries, n), np.float64)
+        en[:n] = [e["energy"] for e in self.entries]
+        en[:n] = np.maximum(0.05, en[:n] * self.decay)
+        slot_of = {e["id"]: j for j, e in enumerate(self.entries)}
         for i in range(len(seeds)):
             h = int(hashes_u64[i])
             hit_crash = bool(crashed[i])
@@ -343,14 +368,26 @@ class Corpus:
                 entry["origin"] = "targeted"
                 targeted_yield += 1
             self._next_id += 1
-            self._insert(entry)
+            if n < self.max_entries:
+                j = n
+                n += 1
+            else:                    # replace the coldest slot
+                j = int(np.argmin(en[:n]))
+                old = self.entries[j]
+                old["energy"] = float(en[j])   # as it left the corpus
+                del slot_of[old["id"]]
+                evicted += 1
+            self._place(entry, j)
+            slot_of[entry["id"]] = j
+            en[j] = entry["energy"]
             if self.track_admissions:
                 self.admitted_unmerged.append(entry)
-            parent = self._by_id.get(int(parent_ids[i]))
-            if parent is not None:
-                parent["energy"] = min(
-                    self.energy_cap, parent["energy"] * self.reward)
-        out = dict(new=new, size=len(self.entries),
+            k = slot_of.get(int(parent_ids[i]))
+            if k is not None:
+                en[k] = min(self.energy_cap, en[k] * self.reward)
+        for e, v in zip(self.entries, en[:n].tolist()):
+            e["energy"] = v
+        out = dict(new=new, evicted=evicted, size=len(self.entries),
                    new_crash_codes=new_crash_codes)
         if op_yield is not None:
             out["op_yield"] = op_yield

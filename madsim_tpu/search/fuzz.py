@@ -173,7 +173,8 @@ def fuzz(rt, max_steps: int, batch: int = 512, max_rounds: int = 16,
 
     observer: obs.metrics.SweepObserver — `on_round` records of kind
     "fuzz_round" (explore's round schema + corpus_size/new_crash_codes,
-    and `host_s`: host seconds by STAGES since the previous record),
+    `admitted` and `evicted` corpus admissions, and `host_s`: host
+    seconds by STAGES since the previous record),
     `on_done` with the final result; hooks ride the harvest the loop
     already blocks on.
 
@@ -546,6 +547,9 @@ def fuzz(rt, max_steps: int, batch: int = 512, max_rounds: int = 16,
                     # budget sits — the fuzzer-effectiveness half of the
                     # profiler plane
                     admitted=cstats["new"],
+                    # admissions that replaced the coldest slot of a
+                    # full corpus (0 while it fills)
+                    evicted=cstats["evicted"],
                     op_yield={YIELD_NAMES[i]: int(cstats["op_yield"][i])
                               for i in range(len(YIELD_NAMES))},
                     corpus_energy=corpus.energy_summary(),
