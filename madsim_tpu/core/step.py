@@ -479,10 +479,10 @@ def make_step(
 
         # ---- 4. materialize emissions into the event table ----------------
         ph.to("step.emit")
-        # All emissions are staged into [E]-vectors and written with ONE
-        # gather+scatter per table column (slots are distinct by
-        # construction), instead of E separate dynamic-index updates — the
-        # difference between ~6 and ~6*E scatter ops per step on TPU.
+        # All emissions are staged per table column and written in one
+        # pass per column (slots are distinct by construction): an
+        # [E]-index scatter or a chain of E selects, never E separate
+        # dynamic-index updates.
         E = n_sends + n_timers
         sent = delivered_drop = jnp.asarray(0, jnp.int32)
         overflow = jnp.asarray(False)
@@ -583,28 +583,32 @@ def make_step(
                         v.astype(col.dtype), mode="drop",
                         unique_indices=True)
             else:
-                # one-hot write instead of an [E]-index scatter (serializes
-                # on TPU, ~10ns/element): real slots are distinct by
-                # construction, so summing the one-hot rows yields each
-                # written value exactly once; masked-off emissions match no
-                # column and write nothing. The [E, C] product is what the
-                # scatter form above avoids on CPU (width tax, DESIGN §5).
+                # a chain of E selects per column, one per emission, instead
+                # of an [E]-index scatter (serializes on TPU, ~10ns/element).
+                # Real slots are distinct by construction and masked-off
+                # emissions target row C, which matches no column, so the
+                # chain writes each value exactly once, in any order. The
+                # selects keep every column lanes-minor: the [E, C] one-hot
+                # product this replaced lowered, for the s32 payload, to a
+                # convolution that pinned the payload table C-minor and
+                # made the pick's payload read a cross-lane reduce (DESIGN
+                # §5). The [E, C] compares are what the scatter form above
+                # avoids on CPU (width tax).
                 slots_eff = jnp.where(
                     w, slots, jnp.asarray(cfg.event_capacity, jnp.int32))
-                slot_oh = slots_eff[:, None] == jnp.arange(
-                    cfg.event_capacity, dtype=jnp.int32)     # [E, C]
-                written = slot_oh.any(0)                     # [C]
+                rows = jnp.arange(cfg.event_capacity, dtype=jnp.int32)
+                # read only by the plane writes below; XLA drops it where
+                # none is compiled
+                written = (slots_eff[:, None] == rows).any(0)  # [C]
 
                 def put(col, vals):
-                    v = jnp.stack(vals)                      # [E] or [E, P]
-                    ohi = slot_oh.astype(v.dtype)
-                    if v.ndim == 1:
-                        upd = (ohi * v[:, None]).sum(0)
+                    for j, v in enumerate(vals):
+                        hit = slots_eff[j] == rows             # [C]
                         # cast, not promote: staged values are int32 but the
                         # column may be a narrow (table_dtype) dtype
-                        return jnp.where(written, upd, col).astype(col.dtype)
-                    upd = jnp.einsum("ec,ep->cp", ohi, v)
-                    return jnp.where(written[:, None], upd, col)
+                        col = jnp.where(hit if col.ndim == 1 else hit[:, None],
+                                        jnp.asarray(v, col.dtype), col)
+                    return col
 
             s = s.replace(
                 t_deadline=put(s.t_deadline, em_deadline),

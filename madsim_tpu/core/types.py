@@ -478,10 +478,11 @@ class SimConfig:
     # of measured completions.
     span_attr: bool = False
     # emission-write lowering: how staged emissions land in the event
-    # table. "onehot" = [E, C] one-hot masked-sum (VPU-friendly — the TPU
-    # default); "scatter" = one XLA scatter per column at distinct slot
-    # rows (O(E) work — the CPU default: the [E, C] product is the
-    # dominant term of the measured n^1.8 cluster-width tax, DESIGN §5).
+    # table. "onehot" = a chain of E selects per column, each against
+    # one emission's [C] one-hot row (the TPU default); "scatter" = one
+    # XLA scatter per column at distinct slot rows (O(E) work — the CPU
+    # default: the [E, C] compares are the dominant term of the measured
+    # n^1.8 cluster-width tax, DESIGN §5).
     # "auto" resolves by backend at trace time. Written VALUES are
     # identical across all three, so trajectories and fingerprints are
     # BIT-IDENTICAL — a lowering lever like table_dtype, not a replay

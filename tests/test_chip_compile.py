@@ -81,14 +81,20 @@ def test_flagship_step_fits_one_chip(topo, no_persistent_cache, flagship):
     assert 0 < used < HBM_BYTES / 8, used
 
 
-def test_fused_runner_shards_over_four_chips(topo, no_persistent_cache,
-                                             flagship):
+@pytest.fixture(scope="module")
+def sharded_runner(topo, no_persistent_cache, flagship):
+    """The flagship's fused runner compiled over four chips (each holds
+    FLAGSHIP_BATCH lanes, the one-chip sweep's shapes)."""
     mesh = Mesh(np.asarray(topo.devices[:4]), ("seeds",))
     shapes = _shapes(flagship, SHARDED_BATCH,
                      NamedSharding(mesh, P("seeds")))
     n_chunks = jax.ShapeDtypeStruct((), jnp.int32,
                                     sharding=NamedSharding(mesh, P()))
-    compiled = flagship._fused_runner.lower(shapes, n_chunks, 512).compile()
+    return flagship._fused_runner.lower(shapes, n_chunks, 512).compile()
+
+
+def test_fused_runner_shards_over_four_chips(sharded_runner):
+    compiled = sharded_runner
     used = _device_bytes(compiled)   # per device
     assert 0 < used < HBM_BYTES / 8, used
     hlo = compiled.as_text()
@@ -100,3 +106,16 @@ def test_fused_runner_shards_over_four_chips(topo, no_persistent_cache,
     assert set(op_scopes(hlo).values()) == {
         "", "step.pick", "step.supervisor", "step.handler", "step.emit",
         "step.check"}
+
+
+def test_emission_write_has_no_convolution(sharded_runner):
+    """The one-hot lowering writes the event table with selects. An s32
+    one-hot product there lowers to a convolution, which pins the payload
+    table with the event rows minor and turns the pick's payload read into
+    a reduce across lanes."""
+    from madsim_tpu.obs.scopes import op_scopes
+    hlo = sharded_runner.as_text()
+    scopes = op_scopes(hlo)
+    convs = [line.split("=", 1)[0].split()[-1] for line in hlo.splitlines()
+             if " convolution(" in line]
+    assert [c for c in convs if scopes[c] == "step.emit"] == []
