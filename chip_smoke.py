@@ -6,7 +6,7 @@
 One chip, in one process, in this order:
   device       platform, device_kind and count; anything but a TPU exits
                non-zero (there is no CPU fallback)
-  flagship     bench._make_runtime's 5-node Raft chaos sweep (kill/restart,
+  flagship     workloads.flagship_raft's 5-node Raft chaos sweep (kill/restart,
                partition/heal, 5% loss) at B=4096 seeds x 4096 steps through
                Runtime.run_fused: no crash, no event-table overflow, lanes live
   parity       the first 64 seeds again on the host CPU backend: fingerprints
@@ -30,15 +30,11 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-import sys
 import time
 
 import numpy as np
 
-ROOT = os.path.dirname(os.path.abspath(__file__))
-
-FLAGSHIP_BATCH = 4096      # bench.B_TPU
+FLAGSHIP_BATCH = 4096      # the v5e sweep batch
 FLAGSHIP_STEPS = 4096
 CHUNK = 512
 PARITY_LANES = 64
@@ -96,8 +92,8 @@ def timed_sweep(rt, seeds, steps: int, run) -> tuple:
 
 
 def flagship_phase():
-    import bench
-    rt = bench._make_runtime()
+    from madsim_tpu.models.workloads import flagship_raft
+    rt = flagship_raft()
     seeds = np.arange(FLAGSHIP_BATCH)
 
     def run(s, n):
@@ -136,12 +132,11 @@ def parity_phase(rt, chip_fps) -> None:
 
 
 def linearizable_phase() -> None:
-    sys.path.insert(0, os.path.join(ROOT, "scripts"))
-    from baseline_configs import config4_runtime
     from madsim_tpu.models.raft_kv import extract_histories
+    from madsim_tpu.models.workloads import raft_kv_config4
     from madsim_tpu.native import check_kv_history
 
-    rt = config4_runtime()
+    rt = raft_kv_config4()
     t0 = time.perf_counter()
     st = rt.run_compacting(rt.init_batch(np.arange(KV_SEEDS)), KV_STEPS,
                            chunk=KV_CHUNK)
@@ -165,10 +160,10 @@ def replay_code(rt, seed: int) -> int:
 
 
 def find_replay_phase() -> None:
-    import bench
+    from madsim_tpu.models.workloads import crashrich
     from madsim_tpu.search.fuzz import fuzz
 
-    rt = bench._make_crashrich_runtime("wal_kv")
+    rt = crashrich("wal_kv")
     t0 = time.perf_counter()
     out = fuzz(rt, FUZZ_STEPS, batch=FUZZ_BATCH, max_rounds=FUZZ_ROUNDS,
                dry_rounds=FUZZ_ROUNDS)
@@ -191,12 +186,12 @@ def find_replay_phase() -> None:
 
 def four_chip_phase() -> None:
     import jax
-    import bench
+    from madsim_tpu.models.workloads import flagship_raft
     from madsim_tpu.parallel.mesh import seed_mesh
     from madsim_tpu.search.shard import fuzz_sharded
 
     devs = jax.devices()[:4]
-    rt = bench._make_runtime()
+    rt = flagship_raft()
     seeds = np.arange(SHARDED_BATCH)
     mesh = seed_mesh(devs)
 
@@ -246,7 +241,6 @@ def main(argv=None) -> None:
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the sharded checks on a 4-chip host")
     args = ap.parse_args(argv)
-    sys.path.insert(0, ROOT)
     info = device_phase(4 if args.four_chips else 1)
     if args.four_chips:
         timed("four-chips", four_chip_phase)

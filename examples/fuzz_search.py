@@ -20,9 +20,9 @@ from madsim_tpu import ProgressObserver, explore, fuzz  # noqa: E402
 
 # fixed-latency chaos: the schedule space seeds alone can reach is small,
 # so the blind sweep goes dry — the regime where searching the knob space
-# (instead of sampling seeds) pays; one shared definition with the
-# search_ab bench and the search tests
-from bench import _make_saturating_runtime as make_runtime  # noqa: E402
+# (instead of sampling seeds) pays; one shared definition with the search
+# tests
+from madsim_tpu.models.workloads import saturating  # noqa: E402
 
 
 def main():
@@ -32,10 +32,10 @@ def main():
               dry_rounds=rounds + 1, chunk=256)
 
     print(f"blind explore(): {rounds} rounds x {batch} seeds")
-    blind = explore(make_runtime(), observer=ProgressObserver(), **kw)
+    blind = explore(saturating(), observer=ProgressObserver(), **kw)
 
     print(f"\nfuzz(): same budget, coverage-guided")
-    res = fuzz(make_runtime(), observer=ProgressObserver(),
+    res = fuzz(saturating(), observer=ProgressObserver(),
                minimize=True, **kw)
 
     print(f"\n  blind:  {blind['distinct_schedules']:>5} distinct "

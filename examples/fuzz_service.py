@@ -28,8 +28,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 from madsim_tpu import ProgressObserver, campaign_report, run_campaign  # noqa: E402
 
 # the crash-rich wal_kv matrix (lost unsynced writes under kill/restart
-# chaos): one shared definition with --mode campaign and the search tests
-FACTORY = "bench:_make_crashrich_runtime"
+# chaos): one shared definition with the campaign and search tests
+FACTORY = "madsim_tpu.models.workloads:crashrich"
 FACTORY_KWARGS = dict(kind="wal_kv", trace_cap=64, sketch_slots=4)
 
 

@@ -26,9 +26,6 @@ Three tiers, from hot to cold:
   * `persistent.py` — the cross-process tier: wires JAX's persistent
     compilation cache (`jax_compilation_cache_dir`) so cold CI processes
     reuse warm on-disk executables.
-
-`timing.py` holds the AOT trace/lower/compile stage timers used by
-`bench.py --mode compile_ab`.
 """
 
 from .cache import COMPILE_LOG, PROGRAM_CACHE, ProgramCache

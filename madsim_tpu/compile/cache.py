@@ -96,8 +96,7 @@ class CompileLog:
     # -- reporting --------------------------------------------------------
     def recent(self, n: int = 20) -> list[dict]:
         """The last `n` compile records (what retraced, when) — the
-        drill-down behind snapshot()'s counters; bench.py --mode
-        compile_ab embeds it in the artifact."""
+        drill-down behind snapshot()'s counters."""
         with self._lock:
             return list(self.events)[-n:]
 

@@ -40,8 +40,8 @@ from . import types as T
 # automatically: excluded from fingerprints (utils/hashing —
 # observation only, never a replay domain), read by obs/rings.py (the
 # tr_* columns) and obs/profiler.py (the pf_* columns), compared
-# explicitly in the fused-vs-chunked equivalence tests and bench.py
-# --obs-smoke / --causal-smoke / --prof-smoke. trace_cap is the
+# explicitly in the fused-vs-chunked equivalence tests (test_obs,
+# test_causal, test_profiler and the other planes). trace_cap is the
 # DYNAMIC capacity operand (columns are sized to the power-of-two
 # bucket, cfg.trace_cap_bucket — DESIGN §10); sketch_every is the
 # DYNAMIC fold period for the structurally sized cov_sketch column
@@ -152,8 +152,8 @@ class SimState:
     # wrapped. Observation only: no randomness consumed, excluded from
     # fingerprints, zero-size when the recorder is compiled out. One
     # [C, 2] matrix, not two [C] columns: the step then pays ONE extra
-    # emission write and ONE extra dispatch gather (the t_payload shape,
-    # half the lineage cost measured by bench.py --mode causal_ab).
+    # emission write and ONE extra dispatch gather (the t_payload shape;
+    # on the CPU, half the cost of two columns).
     ev_prov: jax.Array      # int32[C, 2] — per pending row:
                             # [0] dispatch index of the step that
                             #     enqueued it; -1 = external (scenario

@@ -351,7 +351,7 @@ class SimConfig:
     # fingerprints (TRACE_FIELDS). Per-lane masking rides
     # `init_batch(profile_lanes=...)` — a build can ship with
     # profile=True and flip lanes on per sweep (the masked-off overhead
-    # bar is ≤3% on the tiny-step worst case, bench.py --mode prof_ab).
+    # bar is ≤3% on the tiny-step worst case; judged on the CPU only).
     profile: bool = False
     # SLO latency plane (obs/profiler.py, DESIGN §17): number of log2
     # buckets in the on-device request-latency histograms. 0 (default)

@@ -179,7 +179,7 @@ def detsan_check(rt: Runtime, seeds, max_steps: int, chunk: int = 512, *,
 
     Both runs use the same runner (`fused` selects which) and the same
     executable, so the sanitizer's cost is one extra sweep plus a host
-    diff — the ≤2x contract `bench.py --mode detsan_ab` measures. When
+    diff (≤2x one sweep). When
     `baseline_state` is given (run_seeds already ran the batch), only
     the permuted sweep is paid. With no baseline both sweeps are
     DISPATCHED before either is forced: JAX async dispatch overlaps

@@ -116,8 +116,8 @@ class PgServer(Program):
         # r19 incarnation guard (net/conn.py, net/stream.py): True is the
         # sound default; False compiles the pre-r19 accept-everything
         # transport — the honest red control tests/test_connfault.py and
-        # bench's connfault regime use to PROVE the guard is what makes
-        # exactly-once survive connection churn
+        # the connfault_mix regression corpus use to PROVE the guard is
+        # what makes exactly-once survive connection churn
         self.guard = epoch_guard
 
     # ---- response ring (strict output order + backpressure) -------------

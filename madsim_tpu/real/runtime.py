@@ -124,9 +124,9 @@ class RealRuntime:
                                           self._on_packet)
         # compiled dispatch: jit each (program, handler-kind) once and run
         # events through XLA instead of eager op dispatch — measured
-        # 3.4x on the echo workload (bench.py --realworld: ~0.9ms vs
-        # ~3.2ms per handler event on a 1-core box; remaining cost is
-        # jit-call overhead + host sync + asyncio, not the ops) — toward
+        # 3.4x on the echo workload (~0.9ms vs ~3.2ms per handler event
+        # on a 1-core CPU box; remaining cost is jit-call overhead + host
+        # sync + asyncio, not the ops) — toward
         # the real-mode performance the reference gets from Rust. Opt-in: the
         # first event of each combo pays its compile, which short demo
         # runs may not amortize. Programs are trace-safe by construction
@@ -575,7 +575,7 @@ class RealRuntime:
         if len(events) == 1:
             # depth-1 guard rail: a one-event scan amortizes nothing and
             # pays the stacked-state round-trip (measured 0.64x eager on
-            # the depth-1 ping-pong shape, BENCH_realworld_r04) — run the
+            # the depth-1 ping-pong shape, on the CPU) — run the
             # event through per-event compiled dispatch instead. Key draw
             # order is identical (one key per event in both modes).
             node, kc, src, tag, pl = events[0]

@@ -62,8 +62,7 @@ class Runtime:
         signature — see compile/signature.py), so structurally-identical
         Runtimes share one trace+compile per (batch shape, chunk length).
         False restores private per-instance jits (the fresh-compile
-        control used by the cache-correctness tests and
-        `bench.py --mode compile_ab`).
+        control used by the cache-correctness tests).
     """
 
     def __init__(self, cfg: T.SimConfig, programs: Sequence[Program],
@@ -279,30 +278,27 @@ class Runtime:
         when cfg.profile (None = all; same index/bool-mask forms). The
         masked-off build is the ship-with-it shape: profile=True
         compiled in, lanes flipped on only for the sweeps being
-        profiled (bench.py --mode prof_ab bounds the masked cost).
+        profiled.
 
         latency_lanes: which lanes the SLO latency plane histograms
-        when cfg.latency_hist > 0 (None = all; same forms; bench.py
-        --mode lat_ab bounds the masked cost). NOTE: the root-time
-        column ev_root_t is maintained on every lane regardless — only
-        the histogram folds are gated — so flipping a lane on mid-
-        campaign needs no warm-up. A runtime whose `invariant=` is
+        when cfg.latency_hist > 0 (None = all; same forms). NOTE: the
+        root-time column ev_root_t is maintained on every lane
+        regardless — only the histogram folds are gated — so flipping a
+        lane on mid-campaign needs no warm-up. A runtime whose `invariant=` is
         harness.slo_invariant should keep every lane on: a masked lane
         never folds, so its SLO can never fire.
 
         series_lanes: which lanes the windowed telemetry plane records
-        when cfg.series_windows > 0 (None = all; same forms; bench.py
-        --mode series_ab bounds the masked cost). A runtime whose
-        `invariant=` is harness.recovery_invariant should keep every
-        lane on — a masked lane's windows never fill, so its recovery
+        when cfg.series_windows > 0 (None = all; same forms). A runtime
+        whose `invariant=` is harness.recovery_invariant should keep
+        every lane on — a masked lane's windows never fill, so its recovery
         oracle can never fire (the slo_invariant rule).
 
         span_lanes: which lanes the critical-path attribution plane
-        attributes when cfg.span_attr (None = all; same forms; bench.py
-        --mode span_ab bounds the masked cost). Like ev_root_t, the
-        carried ev_span column is maintained on every lane regardless —
-        only the sa_* counter folds are gated — so flipping a lane on
-        mid-campaign needs no warm-up.
+        attributes when cfg.span_attr (None = all; same forms). Like
+        ev_root_t, the carried ev_span column is maintained on every
+        lane regardless — only the sa_* counter folds are gated — so
+        flipping a lane on mid-campaign needs no warm-up.
         """
         seeds = jnp.atleast_1d(jnp.asarray(seeds, jnp.uint32))
         keys = jax.vmap(prng.seed_key)(seeds)

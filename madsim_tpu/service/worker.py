@@ -8,8 +8,9 @@ instant (the store's write-then-rename contract); relaunching with the
 same arguments resumes where it died.
 
 Factory specs resolve against sys.path plus the current working
-directory, so `--factory bench:_make_crashrich_runtime` works from a
-repo checkout and `--factory mypkg.workloads:make_rt` from an install.
+directory, so `--factory madsim_tpu.models.workloads:crashrich` works
+from a repo checkout and `--factory mypkg.workloads:make_rt` from an
+install.
 """
 
 from __future__ import annotations

@@ -1,6 +1,6 @@
 """(Re)generate the committed regression corpus (tests/data/
-regression_corpus/) — the OSS-Fuzz-style gate `bench.py
---regression-smoke` replays in ci.sh fast.
+regression_corpus/) — the OSS-Fuzz-style gate that
+tests/test_triage.py::TestRegressionCorpus replays.
 
 Runs a small DETERMINISTIC durable fuzz campaign per flagship regime and
 freezes the resulting corpus dir (entries + causal-fingerprint crash
@@ -26,8 +26,8 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-import bench  # noqa: E402
 from madsim_tpu import fuzz  # noqa: E402
+from madsim_tpu.service.worker import resolve_factory  # noqa: E402
 from madsim_tpu.service.store import CorpusStore  # noqa: E402
 
 BASE = os.path.join(REPO, "tests", "data", "regression_corpus")
@@ -36,7 +36,7 @@ CAMPAIGNS = {
     # the r17 gray-failure flagship: Percolator-lite under the composed
     # fault mix (asym cut, drifting clocks, slow disk, torn kill)
     "grayfail_mix": dict(
-        factory="bench:_make_grayfail_runtime",
+        factory="madsim_tpu.models.workloads:grayfail",
         factory_kwargs=dict(recipe="mix"),
         max_steps=30_000, batch=64, max_rounds=4, rng_seed=1),
     # the r19 connection-fault flagship: minipg exactly-once transactions
@@ -44,7 +44,7 @@ CAMPAIGNS = {
     # reset+dup storm (the honest red control — these buckets ARE the
     # stale-segment corruptions the guard exists to prevent)
     "connfault_mix": dict(
-        factory="bench:_make_connfault_runtime",
+        factory="madsim_tpu.models.workloads:connfault",
         factory_kwargs=dict(recipe="mix"),
         max_steps=30_000, batch=64, max_rounds=4, rng_seed=1),
 }
@@ -54,8 +54,7 @@ for name in names:
     spec = CAMPAIGNS[name]
     dest = os.path.join(BASE, name)
     shutil.rmtree(dest, ignore_errors=True)
-    mod, fn = spec["factory"].split(":")
-    rt = getattr(bench, fn)(**spec["factory_kwargs"])
+    rt = resolve_factory(spec["factory"])(**spec["factory_kwargs"])
     res = fuzz(rt, max_steps=spec["max_steps"], batch=spec["batch"],
                max_rounds=spec["max_rounds"],
                dry_rounds=spec["max_rounds"] + 1,
@@ -77,7 +76,7 @@ for name in names:
             max_steps=spec["max_steps"],
             buckets=keys,
             note=("frozen by scripts/make_regression_corpus.py; replayed "
-                  "by bench.py --regression-smoke in ci.sh fast"),
+                  "by tests/test_triage.py::TestRegressionCorpus"),
         ), f, indent=1)
     print(f"{dest}: {len(store.entry_names())} entries, "
           f"{len(keys)} buckets: {keys}")

@@ -39,8 +39,9 @@ RUNS = dict(
 
 
 def build_pingpong():
-    """The saturating pingpong chaos workload (bench.py's regime), with
-    the recorder compiled in so ring columns are covered too."""
+    """The saturating pingpong chaos workload (`workloads.saturating`'s
+    regime), with the recorder compiled in so ring columns are covered
+    too."""
     from madsim_tpu import NetConfig, Runtime, Scenario, SimConfig, ms, sec
     from madsim_tpu.models.pingpong import PingPong, state_spec
     sc = Scenario()

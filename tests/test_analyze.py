@@ -21,14 +21,10 @@ a COMPILE_LOG record naming qualname + cell (satellite 2).
 """
 
 import os
-import sys
 import threading
 
 import numpy as np
 import pytest
-
-sys.path.insert(0, os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 
 from madsim_tpu import DetSanFailure, Program, detsan_check, run_seeds
 from madsim_tpu.analyze.lint import (DeterminismLintError, active,
@@ -296,11 +292,9 @@ class TestRuntimeLint:
 
 
 def _racy_rt(trace_cap=256):
-    """The race-rich wal_kv mutant. bench owns the ONE canonical
-    definition (the r9 rule: tests exercise exactly the workload
-    --analyze-smoke gates)."""
-    from bench import _make_racy_runtime
-    return _make_racy_runtime(trace_cap=trace_cap)
+    """The race-rich wal_kv mutant (`models/workloads.racy`)."""
+    from madsim_tpu.models.workloads import racy
+    return racy(trace_cap=trace_cap)
 
 
 class TestRaces:
@@ -428,8 +422,8 @@ class TestDetSan:
         assert rep["ok"] and rep["diffs"] == []
 
     def test_planted_diff_is_pinned_to_leaf_lane_seed(self):
-        from bench import _make_light_runtime
-        rt = _make_light_runtime(n_nodes=2)
+        from madsim_tpu.models.workloads import light
+        rt = light(n_nodes=2)
         seeds = np.arange(8)
         a = rt.run_fused(rt.init_batch(seeds), 256, 64)
         bad = a.replace(now=a.now.at[3].add(1))
@@ -444,8 +438,8 @@ class TestDetSan:
         assert "MADSIM_TEST_DETSAN" in str(ei.value)
 
     def test_run_seeds_detsan_flag_and_env(self):
-        from bench import _make_light_runtime
-        rt = _make_light_runtime(n_nodes=2)
+        from madsim_tpu.models.workloads import light
+        rt = light(n_nodes=2)
         state = run_seeds(rt, np.arange(8), 256, chunk=64, detsan=True)
         assert np.asarray(state.now).shape == (8,)   # ran + sanitized
         os.environ["MADSIM_TEST_DETSAN"] = "1"

@@ -33,23 +33,23 @@ from madsim_tpu.search.fuzz import WORKER_SEED_STRIDE
 from madsim_tpu.search.mutate import N_MUT_OPS, KnobPlan
 from madsim_tpu.service import (CorpusStore, CrashBuckets, StoreMismatch,
                                 campaign_report, merged_buckets,
-                                replay_bucket, store_signature, worker_cmd)
+                                replay_bucket, run_campaign,
+                                store_signature, worker_cmd)
 from madsim_tpu.service.store import CORPUS_VERSION
+
+CRASHRICH = "madsim_tpu.models.workloads:crashrich"   # the worker factory
 
 
 def _saturating_rt(trace_cap=16, sketch_slots=4):
-    """One canonical workload definition (the r9 rule): the bench owns
-    it, tests import it."""
-    from bench import _make_saturating_runtime
-    return _make_saturating_runtime(trace_cap=trace_cap,
-                                    sketch_slots=sketch_slots)
+    from madsim_tpu.models.workloads import saturating
+    return saturating(trace_cap=trace_cap, sketch_slots=sketch_slots)
 
 
 def _crashrich_rt():
     # trace_cap/batch/steps chosen to SHARE executables with
     # test_causal's fast-lane wal_kv runs (one compile, two files)
-    from bench import _make_crashrich_runtime
-    return _make_crashrich_runtime("wal_kv", trace_cap=128)
+    from madsim_tpu.models.workloads import crashrich
+    return crashrich("wal_kv", trace_cap=128)
 
 
 def _mk_store(tmp_path, rt, plan, name="corpus"):
@@ -396,6 +396,8 @@ class TestCampaignDedup:
             assert sorted(ws) == [0, 1], (key, ws)
         rep = campaign_report(d)
         assert rep["buckets_merged"] == len(store.bucket_keys())
+        # a durable campaign writes its row table for triage on open
+        assert store.load_triage_rows() is not None
 
 
 class TestOneProcessPerChip:
@@ -410,7 +412,7 @@ class TestOneProcessPerChip:
                             lambda: True)
         monkeypatch.setattr(campaign, "spawn_worker", None)  # never reached
         with pytest.raises(ValueError, match="shards="):
-            campaign.run_campaign("bench:_make_crashrich_runtime",
+            campaign.run_campaign(CRASHRICH,
                                   str(tmp_path), workers=2, max_steps=64,
                                   env={"PATH": os.environ["PATH"]})
 
@@ -433,15 +435,14 @@ class TestOneProcessPerChip:
 @pytest.mark.slow
 class TestCampaignProcesses:
     """The real multi-process contracts (subprocess workers pay a jax
-    import + compile each; scripts/ci.sh fast covers the same ground
-    through `bench.py --campaign-smoke`)."""
+    import + compile each)."""
 
     def _env(self):
         return dict(os.environ, JAX_PLATFORMS="cpu")
 
     def _cmd(self, d, worker, rounds):
         return worker_cmd(
-            d, worker, "bench:_make_crashrich_runtime",
+            d, worker, CRASHRICH,
             factory_kwargs=dict(kind="wal_kv", trace_cap=64,
                                 sketch_slots=4),
             max_steps=4096, batch=16, max_rounds=rounds, chunk=512)
@@ -467,6 +468,28 @@ class TestCampaignProcesses:
         assert sk.coverage_keys() == sc.coverage_keys()
         assert sk.entry_names() == sc.entry_names()
         assert sk.bucket_keys() == sc.bucket_keys()
+
+    def test_two_concurrent_workers_merge_and_dedup(self, tmp_path):
+        d = str(tmp_path / "merge")
+        rep = run_campaign(
+            CRASHRICH, d, workers=2, env=self._env(), poll_s=1.0,
+            factory_kwargs=dict(kind="wal_kv", trace_cap=64,
+                                sketch_slots=4),
+            max_steps=4096, batch=16, max_rounds=2, chunk=512)
+        for w, res in rep["worker_results"].items():
+            assert res["returncode"] == 0, (w, res)
+        store = CorpusStore(d, create=False)
+        assert {n.split("-")[0] for n in store.entry_names()} \
+            == {"w0000", "w0001"}
+        # entries can transiently exceed coverage when both workers admit
+        # one hash before their next merge sync — never the reverse
+        assert 0 < rep["coverage_keys"] <= rep["corpus_entries"]
+        assert rep["buckets_merged"] >= 1
+        assert rep["crash_observations"] > rep["buckets_merged"]
+        seen_by = {}
+        for line in store.bucket_log():
+            seen_by.setdefault(line["bucket"], set()).add(line["worker_id"])
+        assert any(len(ws) == 2 for ws in seen_by.values()), seen_by
 
     def test_replay_bucket_reproduces_crash(self, tmp_path):
         d = str(tmp_path / "camp")

@@ -48,13 +48,10 @@ def _pingpong_rt(trace_cap=0, sketch_slots=0, sketch_every=64, target=3,
 
 
 def _crashrich_wal_kv(trace_cap=0, sketch_slots=0):
-    """The crash-rich wal_kv chaos matrix. bench owns the ONE canonical
-    definition (the r9 rule: tests exercise exactly the workload the
-    bench measures — test_search imports its saturating runtime the
-    same way), so retuning the bench can't silently fork this test."""
-    from bench import _make_crashrich_runtime
-    return _make_crashrich_runtime("wal_kv", trace_cap=trace_cap,
-                                   sketch_slots=sketch_slots)
+    """The crash-rich wal_kv chaos matrix (`models/workloads`)."""
+    from madsim_tpu.models.workloads import crashrich
+    return crashrich("wal_kv", trace_cap=trace_cap,
+                     sketch_slots=sketch_slots)
 
 
 def _nontrace_state(state) -> dict:

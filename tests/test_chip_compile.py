@@ -22,7 +22,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 HBM_BYTES = 16e9          # one v5e chip
-FLAGSHIP_BATCH = 4096     # bench.B_TPU, chip_smoke.py's one-chip sweep
+FLAGSHIP_BATCH = 4096     # the v5e sweep batch, chip_smoke.py's one-chip sweep
 SHARDED_BATCH = 16384     # chip_smoke.py --four-chips
 
 
@@ -52,10 +52,10 @@ def no_persistent_cache():
 
 @pytest.fixture(scope="module")
 def flagship():
-    import bench
+    from madsim_tpu.models.workloads import flagship_raft
     # "auto" would resolve against this process's CPU backend and compile
     # the scatter lowering; the chip runs the one-hot form
-    return bench._make_runtime(emission_write="onehot")
+    return flagship_raft(emission_write="onehot")
 
 
 def _shapes(rt, batch: int, sharding):

@@ -19,9 +19,6 @@ pre-r19 behavior; (8) pre-r19 checkpoints are rejected loudly
 (simconfig-v6).
 """
 
-import os
-import sys
-
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -32,9 +29,6 @@ from madsim_tpu.core import prng, types as T
 from madsim_tpu.net import conn, stream
 
 import _connfault_golden as golden
-
-sys.path.insert(0, os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 
 
 # ---------------------------------------------------------------------------
@@ -370,8 +364,8 @@ class TestScenarioRoundTrip:
 
     def test_to_scenario_mutants_still_parse(self):
         import jax
-        import bench
-        rt = bench._make_connfault_runtime("mix", trace_cap=0)
+        from madsim_tpu.models.workloads import connfault
+        rt = connfault("mix", trace_cap=0)
         plan = KnobPlan.from_runtime(rt)
         text = plan.to_scenario(plan.base_knobs()).describe()
         assert "set_dup" in text and "reset_peer" in text
@@ -400,8 +394,8 @@ class TestScenarioRoundTrip:
 class TestKnobPlan:
     def test_bounds_and_pools(self):
         import jax
-        import bench
-        rt = bench._make_connfault_runtime("mix", trace_cap=0)
+        from madsim_tpu.models.workloads import connfault
+        rt = connfault("mix", trace_cap=0)
         plan = KnobPlan.from_runtime(rt)
         dup_rows = plan.base["op"] == T.OP_SET_DUP
         rp_rows = plan.base["op"] == T.OP_RESET_PEER
@@ -417,8 +411,8 @@ class TestKnobPlan:
         assert int(hist[-1]) > 0, "fault_perturb never applied"
 
     def test_apply_clips_hand_edited_rate(self):
-        import bench
-        rt = bench._make_connfault_runtime("mix", trace_cap=0)
+        from madsim_tpu.models.workloads import connfault
+        rt = connfault("mix", trace_cap=0)
         plan = KnobPlan.from_runtime(rt)
         kn = plan.base_knobs()
         kn["row_val"] = np.full(plan.R, 10**9, np.int32)
@@ -436,12 +430,12 @@ class TestKnobPlan:
         # rows must add ZERO traces — mutation stays operand traffic
         from madsim_tpu import fuzz
         from madsim_tpu.compile.cache import COMPILE_LOG
-        import bench
+        from madsim_tpu.models.workloads import connfault
         kw = dict(max_steps=2_000, batch=16, max_rounds=3, dry_rounds=4,
                   chunk=256)
-        fuzz(bench._make_connfault_runtime("mix", trace_cap=0), **kw)
+        fuzz(connfault("mix", trace_cap=0), **kw)
         before = COMPILE_LOG.snapshot()["traces_total"]
-        fuzz(bench._make_connfault_runtime("mix", trace_cap=0), **kw)
+        fuzz(connfault("mix", trace_cap=0), **kw)
         after = COMPILE_LOG.snapshot()["traces_total"]
         assert after == before, COMPILE_LOG.recent(8)
 
@@ -461,8 +455,8 @@ class TestFlagship:
         assert not np.asarray(fin.crashed).any()
 
     def test_green_under_churn_with_guards(self):
-        import bench
-        rt = bench._make_connfault_runtime("mix", guard=True)
+        from madsim_tpu.models.workloads import connfault
+        rt = connfault("mix", guard=True)
         fin = rt.run_fused(
             rt.init_batch(np.arange(48, dtype=np.uint32)), 120_000, 512)
         done = np.asarray(fin.node_state["c_done"])[:, 1:]
@@ -470,8 +464,8 @@ class TestFlagship:
         assert not np.asarray(fin.crashed).any()
 
     def test_red_without_guards(self):
-        import bench
-        rt = bench._make_connfault_runtime("mix")      # guards OFF
+        from madsim_tpu.models.workloads import connfault
+        rt = connfault("mix")      # guards OFF
         fin = rt.run_fused(
             rt.init_batch(np.arange(48, dtype=np.uint32)), 120_000, 512)
         crashed = np.asarray(fin.crashed)
@@ -483,14 +477,28 @@ class TestFlagship:
         assert (codes > 0).any(), codes
 
     @pytest.mark.slow
+    def test_red_lane_replays_by_seed(self):
+        from madsim_tpu.models.workloads import connfault
+        rt = connfault("mix")                          # guards OFF
+        fin = rt.run_fused(
+            rt.init_batch(np.arange(48, dtype=np.uint32)), 120_000, 512)
+        lane = int(np.nonzero(np.asarray(fin.crashed))[0][0])
+        rep = rt.run_fused(rt.init_batch(np.asarray([lane], np.uint32)),
+                           120_000, 512)
+        assert int(np.asarray(rep.crash_code)[0]) \
+            == int(np.asarray(fin.crash_code)[lane])
+        assert int(rt.fingerprints(rep)[0]) \
+            == int(rt.fingerprints(fin)[lane])
+
+    @pytest.mark.slow
     def test_red_opens_replaying_causal_bucket(self):
         import shutil
         import tempfile
-        import bench
+        from madsim_tpu.models.workloads import connfault
         from madsim_tpu import fuzz, replay_bucket
         tmp = tempfile.mkdtemp(prefix="connfault_bucket_")
         try:
-            rt = bench._make_connfault_runtime("mix")
+            rt = connfault("mix")
             res = fuzz(rt, max_steps=30_000, batch=64, max_rounds=3,
                        dry_rounds=4, chunk=512, corpus_dir=tmp)
             assert res["buckets_total"] >= 1, res
@@ -503,9 +511,9 @@ class TestFlagship:
 
     @pytest.mark.slow
     def test_recovery_recipes_green_with_guards(self):
-        import bench
+        from madsim_tpu.models.workloads import connfault
         for recipe in ("reset", "dup", "half"):
-            rt = bench._make_connfault_runtime(recipe)
+            rt = connfault(recipe)
             fin = rt.run_fused(
                 rt.init_batch(np.arange(48, dtype=np.uint32)),
                 120_000, 512)

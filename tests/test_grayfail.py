@@ -146,6 +146,27 @@ class TestOneWayPartition:
         sc.at(ms(20)).partition_oneway([0, 1], direction=1)
         np.testing.assert_array_equal(self._final_clog(sc), want | want.T)
 
+    @pytest.mark.parametrize("direction,rumors_leave", [(0, False),
+                                                        (1, True)])
+    def test_gossip_sees_the_cut_from_one_side(self, direction,
+                                               rumors_leave):
+        # a protocol-level view of the cut around node 0: outbound
+        # (direction 0) no rumor ever leaves node 0; inbound (1) node 0
+        # hears nothing but its sends flow, so dissemination completes
+        from madsim_tpu.models.gossip import make_gossip_runtime
+        sc = Scenario()
+        sc.at(0).partition_oneway([0], direction=direction)
+        cfg = SimConfig(n_nodes=6, event_capacity=192, time_limit=sec(2))
+        rt = make_gossip_runtime(n_nodes=6, n_rumors=4, scenario=sc,
+                                 cfg=cfg)
+        fin = rt.run_fused(rt.init_batch(np.arange(8, dtype=np.uint32)),
+                           6_000, 256)
+        have = np.asarray(fin.node_state["have"])     # rumor bitmask
+        if rumors_leave:
+            assert (have == (1 << 4) - 1).all()       # all four, everywhere
+        else:
+            assert (have[:, 1:] == 0).all()
+
     def test_heal_clears_oneway_cuts(self):
         sc = Scenario()
         sc.at(ms(10)).partition_oneway([0, 1], direction=0)
@@ -371,8 +392,8 @@ class TestScenarioRoundTrip:
 # ---------------------------------------------------------------------------
 
 def _gray_rt():
-    import bench
-    return bench._make_grayfail_runtime("mix", trace_cap=0, n_ops=8)
+    from madsim_tpu.models.workloads import grayfail
+    return grayfail("mix", trace_cap=0, n_ops=8)
 
 
 class TestKnobPlan:
@@ -453,11 +474,42 @@ class TestPercolator:
         assert ei.value.code == CRASH_SNAPSHOT
 
     @pytest.mark.slow
-    def test_every_gray_recipe_goes_red(self):
-        import bench
+    def test_skew_crash_replays_by_seed(self):
+        # skewed lease expiry crashes the snapshot oracle, and the red
+        # lane's seed replays alone to the same code and fingerprint
         from madsim_tpu.models.percolator import CRASH_SNAPSHOT
+        from madsim_tpu.models.workloads import grayfail
+        rt = grayfail("skew")
+        fin = rt.run_fused(rt.init_batch(np.arange(192, dtype=np.uint32)),
+                           80_000, 512)
+        lanes = np.nonzero(np.asarray(fin.crash_code) == CRASH_SNAPSHOT)[0]
+        assert lanes.size > 0
+        lane = int(lanes[0])
+        rep = rt.run_fused(rt.init_batch(np.asarray([lane], np.uint32)),
+                           80_000, 512)
+        assert int(np.asarray(rep.crash_code)[0]) == CRASH_SNAPSHOT
+        assert int(rt.fingerprints(rep)[0]) \
+            == int(rt.fingerprints(fin)[lane])
+
+    @pytest.mark.slow
+    def test_torn_campaign_opens_replaying_buckets(self, tmp_path):
+        from madsim_tpu import fuzz, replay_bucket
+        from madsim_tpu.models.workloads import grayfail
+        rt = grayfail("torn")
+        d = str(tmp_path / "torn")
+        res = fuzz(rt, max_steps=40_000, batch=64, max_rounds=3,
+                   dry_rounds=4, chunk=512, corpus_dir=d)
+        assert res["buckets_total"] >= 1, res
+        for key in res["buckets_opened"]:
+            crashed, code, _ = replay_bucket(rt, d, key, 40_000)
+            assert crashed, (key, code)
+
+    @pytest.mark.slow
+    def test_every_gray_recipe_goes_red(self):
+        from madsim_tpu.models.percolator import CRASH_SNAPSHOT
+        from madsim_tpu.models.workloads import grayfail
         for recipe in ("skew", "asym", "disk", "torn"):
-            rt = bench._make_grayfail_runtime(recipe, trace_cap=0)
+            rt = grayfail(recipe, trace_cap=0)
             fin = rt.run_fused(
                 rt.init_batch(np.arange(192, dtype=np.uint32)),
                 80_000, 512)

@@ -53,6 +53,23 @@ def _pingpong_rt(lat=24, target=6, n_nodes=2, scenario=None, loss=0.0,
                    scenario=scenario, invariant=invariant)
 
 
+def _echo_sweep():
+    """A direct request/reply rpc_echo sweep whose 512-slot ring holds
+    every dispatch: reply deliveries complete a call and root the next.
+    Returns (runtime, reply tag, final state)."""
+    from madsim_tpu.models.rpc_echo import TAG_ECHO, make_echo_runtime
+    from madsim_tpu.net import rpc
+    rtag = rpc.reply_tag(TAG_ECHO)
+    cfg = SimConfig(n_nodes=3, event_capacity=64, time_limit=sec(5),
+                    latency_hist=24, trace_cap=512,
+                    complete_kinds=((EV_MSG, rtag),),
+                    root_kinds=((EV_MSG, rtag),),
+                    net=NetConfig(send_latency_min=ms(1),
+                                  send_latency_max=ms(8)))
+    rt = make_echo_runtime(n_nodes=3, target=6, cfg=cfg)
+    return rt, rtag, rt.run_fused(rt.init_batch(np.arange(6)), 1024, 256)
+
+
 def _nonlatency_state(state) -> dict:
     out = {}
     for name in type(state).__dataclass_fields__:
@@ -157,17 +174,7 @@ class TestLatencyPlane:
         # root-inheritance end to end on a direct request/reply chain:
         # every ring completion's tr_lat equals now(completion) −
         # now(its chain's root), roots being external dispatches
-        from madsim_tpu.models.rpc_echo import TAG_ECHO, make_echo_runtime
-        from madsim_tpu.net import rpc
-        rtag = rpc.reply_tag(TAG_ECHO)
-        cfg = SimConfig(n_nodes=3, event_capacity=64, time_limit=sec(5),
-                        latency_hist=24, trace_cap=512,
-                        complete_kinds=((EV_MSG, rtag),),
-                        root_kinds=((EV_MSG, rtag),),
-                        net=NetConfig(send_latency_min=ms(1),
-                                      send_latency_max=ms(8)))
-        rt = make_echo_runtime(n_nodes=3, target=6, cfg=cfg)
-        st = rt.run_fused(rt.init_batch(np.arange(6)), 1024, 256)
+        rt, rtag, st = _echo_sweep()
         checked = 0
         for b in range(6):
             recs = ring_records(st, b)
@@ -190,6 +197,25 @@ class TestLatencyPlane:
                 assert int(lat[i]) == int(recs["now"][i]) - root_now
                 checked += 1
         assert checked > 0
+
+    def test_e2e_histogram_equals_ring_bucketing(self):
+        # ring -> histogram: each lane's ring latencies, bucketed by the
+        # histogram rule, are that lane's device e2e histogram, and the
+        # digest's batch merge is the exact sum over lanes
+        _, _, st = _echo_sweep()
+        lh = np.asarray(st.lh_e2e)                     # [B, N, LB]
+        LB = lh.shape[2]
+        for b in range(lh.shape[0]):
+            recs = ring_records(st, b)
+            assert recs["dropped"] == 0
+            lat = np.asarray(recs["lat"])
+            ref = np.zeros(LB, np.int64)
+            for v in lat[lat >= 0]:
+                ref[0 if v == 0 else min(int(v).bit_length(), LB - 1)] += 1
+            assert (lh[b].sum(0) == ref).all(), (b, lh[b].sum(0), ref)
+        assert lh.sum() > 0
+        assert (np.asarray(latency_counters(st)["e2e_hist"])
+                == lh.sum(0)).all()
 
     def test_scenario_row_mints_root_at_dispatch(self):
         # deferred boots are external causes that mint roots at THEIR
@@ -459,10 +485,10 @@ class TestSloInvariant:
 
 class TestLatBonusAndRecords:
     def test_corpus_lat_bonus_scales_admission_energy(self):
-        from bench import _make_saturating_runtime
+        from madsim_tpu.models.workloads import saturating
         from madsim_tpu.search.corpus import Corpus
         from madsim_tpu.search.mutate import KnobPlan
-        rt = _make_saturating_runtime()
+        rt = saturating()
         plan = KnobPlan.from_runtime(rt)
         c = Corpus(plan, lat_bonus=1.0)
         kb = plan.base_batch(2)

@@ -163,6 +163,69 @@ def _echo_ldfi_rt(trace_cap=64, target=3):
                              scenario=sc)
 
 
+class TestLiveRing:
+    """Extraction and targeted replay on a real run of the four-family
+    echo workload (the classes around it feed hand-built records)."""
+
+    @pytest.fixture(scope="class")
+    def green(self):
+        rt = _echo_ldfi_rt()
+        state, _ = rt.run(rt.init_batch(np.arange(8, dtype=np.uint32)),
+                          3000, 256)
+        assert not np.asarray(state.crashed)[0], "lane 0 went red"
+        return rt, state
+
+    def test_extraction_matches_inline_parent_walk(self, green):
+        from madsim_tpu.obs import ring_records
+        from madsim_tpu.obs.support import extract_support
+        _, state = green
+        sup = extract_support(state, 0)
+        assert sup is not None and not sup["truncated"]
+        # an independent walk over the same ring: parent links back from
+        # the last dispatch (the default witness) to the external root
+        recs = ring_records(state, 0)
+        by_step = {int(s): i for i, s in enumerate(recs["step"])}
+        i = len(recs["step"]) - 1
+        msgs, timers = [], []
+        while True:
+            kind = int(recs["kind"][i])
+            if kind == T.EV_MSG:
+                msgs.append((int(recs["src"][i]), int(recs["node"][i]),
+                             int(recs["now"][i])))
+            elif kind == T.EV_TIMER:
+                timers.append((int(recs["node"][i]), int(recs["now"][i])))
+            parent = int(recs["parent"][i])
+            if parent < 0 or parent not in by_step:
+                break
+            i = by_step[parent]
+        assert msgs, "the witness chain carries no message edge"
+        assert sup["msg_edges"] == msgs[::-1]
+        assert sup["timer_edges"] == timers[::-1]
+        assert support_from_records(recs)["depth"] == sup["depth"]
+
+    def test_targeted_vector_replays_bit_identically(self, green):
+        from madsim_tpu.obs.support import extract_support
+        rt, state = green
+        plan = KnobPlan.from_runtime(rt, dup_slots=2)
+        pool = SupportPool()
+        for lane in range(4):
+            sup = extract_support(state, lane)
+            if sup is not None:
+                pool.add(sup)
+        assert len(pool) >= 2
+        vecs = synthesize(plan, pool, 8)
+        assert vecs
+        runs = []
+        for _ in range(2):
+            st = plan.apply(rt.init_batch(np.asarray([5], np.uint32)),
+                            KnobPlan.stack([vecs[0]]))
+            fin = rt.run_fused(st, 3000, 256)
+            runs.append((int(rt.fingerprints(fin)[0]),
+                         bool(np.asarray(fin.crashed)[0]),
+                         int(np.asarray(fin.crash_code)[0])))
+        assert runs[0] == runs[1]
+
+
 class TestSynthesize:
     def _pool(self):
         pool = SupportPool()

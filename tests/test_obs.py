@@ -112,8 +112,7 @@ class TestRingEquivalence:
     state (ring included), and the ring itself an observer that never
     perturbs the trajectory. The raft/wal_kv/shard_kv chaos sweeps are
     `slow` (r7 durations triage); the fast lane keeps the pingpong
-    perturbation check here plus the fused-equality assert inside
-    `bench.py --obs-smoke` (ci.sh fast)."""
+    perturbation and fused-equality checks."""
 
     def _assert_fused_equals_chunked(self, rt, seeds, max_steps, chunk):
         chunked, _ = rt.run(rt.init_batch(seeds), max_steps, chunk)
@@ -176,6 +175,24 @@ class TestRingEquivalence:
                                 n_ops=4, max_cfg=4, cfg=cfg)
         self._assert_fused_equals_chunked(
             rt, np.arange(64, dtype=np.uint32), 4096, 512)
+
+    def test_pingpong_fused_equals_chunked_with_sampled_ring(self):
+        # the fast lane's fused-equality check: two sampled lanes, a ring
+        # that wraps, and every ring column reproduced by the fused
+        # runner; a sampled lane reads back min(total, cap) records
+        rt = _pingpong_rt(trace_cap=32, target=1 << 30)
+        seeds = np.arange(16)
+        chunked, _ = rt.run(rt.init_batch(seeds, trace_lanes=[0, 5]),
+                            192, 64)
+        fused = rt.run_fused(rt.init_batch(seeds, trace_lanes=[0, 5]),
+                             192, 64)
+        assert (rt.fingerprints(fused) == rt.fingerprints(chunked)).all()
+        for f in _TRACE_FIELDS:
+            assert (np.asarray(getattr(fused, f))
+                    == np.asarray(getattr(chunked, f))).all(), f
+        recs = ring_records(fused, lane=5)
+        assert recs["total"] > 32
+        assert len(recs["now"]) == min(recs["total"], 32)
 
     def test_fingerprints_ignore_sampling_mask(self):
         # partial lane sampling must not split fingerprints: the same

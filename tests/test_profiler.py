@@ -343,9 +343,9 @@ class TestDigestAndReport:
 
 class TestYieldAttribution:
     def test_mutate_returns_last_op(self):
-        from bench import _make_saturating_runtime
+        from madsim_tpu.models.workloads import saturating
         from madsim_tpu.search.mutate import N_MUT_OPS, KnobPlan
-        rt = _make_saturating_runtime()
+        rt = saturating()
         plan = KnobPlan.from_runtime(rt, dup_slots=2)
         _, hist, last = plan.mutate(plan.base_batch(16),
                                     jax.random.PRNGKey(0), havoc=4)
@@ -359,9 +359,9 @@ class TestYieldAttribution:
         assert np.asarray(z_hist).sum() == 0
 
     def test_mutate_masked_clears_attribution(self):
-        from bench import _make_saturating_runtime
+        from madsim_tpu.models.workloads import saturating
         from madsim_tpu.search.mutate import KnobPlan
-        rt = _make_saturating_runtime()
+        rt = saturating()
         plan = KnobPlan.from_runtime(rt, dup_slots=2)
         mask = np.zeros(16, bool)
         mask[8:] = True
@@ -373,9 +373,9 @@ class TestYieldAttribution:
         assert (last[8:] >= 0).any()
 
     def test_round_yield_sums_to_admissions(self):
-        from bench import _make_saturating_runtime
+        from madsim_tpu.models.workloads import saturating
         from madsim_tpu.search.fuzz import fuzz
-        rt = _make_saturating_runtime()
+        rt = saturating()
         obs = JsonlObserver(io.StringIO())
         res = fuzz(rt, max_steps=400, batch=32, max_rounds=4,
                    dry_rounds=9, chunk=128, rng_seed=0, observer=obs)
@@ -389,10 +389,10 @@ class TestYieldAttribution:
         assert res["corpus_energy"]["entries"] == res["corpus_size"]
 
     def test_corpus_energy_summary(self):
-        from bench import _make_saturating_runtime
+        from madsim_tpu.models.workloads import saturating
         from madsim_tpu.search.corpus import Corpus
         from madsim_tpu.search.mutate import KnobPlan
-        rt = _make_saturating_runtime()
+        rt = saturating()
         plan = KnobPlan.from_runtime(rt)
         c = Corpus(plan)
         assert c.energy_summary() == dict(entries=0)
@@ -417,18 +417,18 @@ class TestCampaignTimeline:
         # run re-derives the interrupted round identically) plus a
         # second worker — the folded timeline must be gapless and
         # dedup'd per worker
-        from bench import _make_saturating_runtime
+        from madsim_tpu.models.workloads import saturating
         from madsim_tpu.search.fuzz import fuzz
         from madsim_tpu.service.campaign import (campaign_report,
                                                  campaign_timeline)
         from madsim_tpu.service.store import CorpusStore
         d = str(tmp_path / "c")
-        rt = _make_saturating_runtime()
-        # warm every executable OUTSIDE the measured campaign (the
-        # bench.py A/B pattern): the staleness flag compares real wall
-        # gaps, and a cold compile landing inside one worker's run but
-        # not another's (e.g. the first suite run after a structural-
-        # signature bump) would skew age-vs-cadence into a flake
+        rt = saturating()
+        # warm every executable OUTSIDE the measured campaign: the
+        # staleness flag compares real wall gaps, and a cold compile
+        # landing inside one worker's run but not another's (e.g. the
+        # first suite run after a structural-signature bump) would skew
+        # age-vs-cadence into a flake
         fuzz(rt, corpus_dir=str(tmp_path / "warm"), worker_id=0,
              max_rounds=2, **self._fuzz_kw())
         fuzz(rt, corpus_dir=d, worker_id=0, max_rounds=2,

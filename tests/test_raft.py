@@ -334,3 +334,28 @@ class TestInvariantForms:
         st, N, L = self._slid_window_divergence_state()
         bad, _ = R.raft_invariant(N, L, window_slides=False)(st)
         assert not bool(bad)
+
+
+class TestFlagshipSweep:
+    """The chaos flagship of `models/workloads` (the chip sweep's
+    workload) and its native single-seed twin (native/simloop.cpp)."""
+
+    def test_flagship_runs_clean(self):
+        # rolling kill/restart/partition under 5% loss: no lane crashes,
+        # the 96-row event table never overflows, and lanes stay live
+        # (the time limit is 600 s of simulated time)
+        from madsim_tpu.models.workloads import flagship_raft
+        rt = flagship_raft()
+        state, _ = rt.run(rt.init_batch(np.arange(64)), 256, 128)
+        assert not np.asarray(state.crashed).any()
+        assert not (np.asarray(state.oops) != 0).any()
+        assert float(np.asarray(~state.halted).mean()) > 0.9
+
+    def test_native_twin_elects_and_commits(self):
+        from madsim_tpu.native import native_baseline_run
+        runs = [native_baseline_run(seed, 2048) for seed in range(8)]
+        assert all(r is not None for r in runs), "no C++ toolchain"
+        assert all(r["events"] > 0 for r in runs)
+        assert max(r["max_commit"] for r in runs) > 0
+        assert sum(r["elections"] for r in runs) >= len(runs)
+

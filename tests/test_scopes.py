@@ -8,9 +8,6 @@ the map from a compiled program back to it reads it right.
 
 from __future__ import annotations
 
-import os
-import sys
-
 import numpy as np
 import pytest
 
@@ -153,9 +150,7 @@ def test_stages_time_and_reset():
 def test_fuzz_rounds_carry_host_stage_seconds(durable, tmp_path):
     from madsim_tpu.obs import SweepObserver
     from madsim_tpu.search.fuzz import STAGES, fuzz
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from bench import _make_saturating_runtime
+    from madsim_tpu.models.workloads import saturating
 
     class Rec(SweepObserver):
         def __init__(self):
@@ -165,7 +160,7 @@ def test_fuzz_rounds_carry_host_stage_seconds(durable, tmp_path):
             self.rounds.append(rec)
 
     obs = Rec()
-    fuzz(_make_saturating_runtime(target=6), max_steps=600, batch=16,
+    fuzz(saturating(target=6), max_steps=600, batch=16,
          max_rounds=3, dry_rounds=5, chunk=128, observer=obs,
          corpus_dir=str(tmp_path / "corpus") if durable else None)
     assert len(obs.rounds) == 3

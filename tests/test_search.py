@@ -17,14 +17,10 @@ from madsim_tpu.models.pingpong import PingPong, state_spec
 
 def _saturating_rt(target=6):
     """Fixed-latency chaos: seeds alone exhaust the schedule space fast —
-    the regime where search beats sampling. ONE definition, shared with
-    bench --mode search_ab and examples/fuzz_search.py."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from bench import _make_saturating_runtime
-    return _make_saturating_runtime(target=target)
+    the regime where search beats sampling (`models/workloads`, shared
+    with examples/fuzz_search.py)."""
+    from madsim_tpu.models.workloads import saturating
+    return saturating(target=target)
 
 
 def _chaos_raft(n_cmds=4):
@@ -377,17 +373,13 @@ class TestFlagshipAcceptance:
         # schedule, so blind explore() sits at the per-lane ceiling here;
         # the fuzzer must MATCH that ceiling (its mutants may not collapse
         # coverage) while it strictly dominates where blind saturates
-        # (test_beats_blind_explore_on_saturating_space and
-        # bench --mode search_ab measure that regime).
-        import os
-        import sys
-        sys.path.insert(0, os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        from bench import _make_runtime
+        # (test_beats_blind_explore_on_saturating_space measures that
+        # regime).
+        from madsim_tpu.models.workloads import flagship_raft
         kw = dict(max_steps=768, batch=512, max_rounds=2, dry_rounds=3,
                   chunk=256)
-        blind = explore(_make_runtime(), **kw)
-        res = fuzz(_make_runtime(), **kw)
+        blind = explore(flagship_raft(), **kw)
+        res = fuzz(flagship_raft(), **kw)
         assert res["distinct_schedules"] >= blind["distinct_schedules"]
         assert res["distinct_schedules"] == res["seeds_run"]  # ceiling
 

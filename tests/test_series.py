@@ -473,21 +473,24 @@ class TestRecoveryInvariant:
 
     @pytest.mark.slow
     def test_flagship_green_red_and_seed_replay(self):
-        # the canonical recovery flagship (bench._make_recovery_runtime):
+        # the canonical recovery flagship (models/workloads.recovery):
         # a clogged-then-unclogged echo cluster recovers inside the
         # grace windows (green); the unhealed latency fault keeps p99
         # pinned past them (red, CRASH_RECOVERY), and the crash replays
         # fingerprint-exact by seed — the repro contract
-        from bench import _make_recovery_runtime
+        from madsim_tpu.models.workloads import recovery
         inv = recovery_invariant(p99_le=ms(20), within=4, min_count=8)
         seeds = np.arange(8, dtype=np.uint32)
-        rt_g = _make_recovery_runtime("heal", invariant=inv)
+        rt_g = recovery("heal", invariant=inv)
         g = rt_g.run_fused(rt_g.init_batch(seeds), 40000, 2048)
         assert not np.asarray(g.crashed).any()
+        # green lanes outlive the 8 x 625 ms timeline: the post-heal
+        # windows were judged, not skipped
+        assert (np.asarray(g.now) >= 8 * ms(625)).all()
         f = np.asarray(g.sr_fault)
         assert (f[:, 1] & T.SRF_PARTITION != 0).all()
         assert (f[:, 4] & T.SRF_HEAL != 0).all()
-        rt_r = _make_recovery_runtime("noheal", invariant=inv)
+        rt_r = recovery("noheal", invariant=inv)
         a = rt_r.run_fused(rt_r.init_batch(seeds), 40000, 2048)
         b = rt_r.run_fused(rt_r.init_batch(seeds), 40000, 2048)
         assert (np.asarray(a.crash_code) == CRASH_RECOVERY).all()
@@ -502,10 +505,10 @@ class TestRecoveryInvariant:
 
 class TestBurstBonus:
     def test_corpus_burst_bonus_scales_admission_energy(self):
-        from bench import _make_saturating_runtime
+        from madsim_tpu.models.workloads import saturating
         from madsim_tpu.search.corpus import Corpus
         from madsim_tpu.search.mutate import KnobPlan
-        rt = _make_saturating_runtime()
+        rt = saturating()
         plan = KnobPlan.from_runtime(rt)
         c = Corpus(plan, burst_bonus=1.0)
         kb = plan.base_batch(2)
@@ -523,6 +526,26 @@ class TestBurstBonus:
                    np.full(2, -1, np.int64), 0,
                    burst=np.asarray([100, 1000], np.int32))
         assert all(e["energy"] == 1.0 for e in c0.entries)
+
+    @pytest.mark.slow
+    def test_burst_fuzz_opens_a_replaying_recovery_crash(self):
+        # mutants of the healed recovery flagship that move the unclog,
+        # fatten the floor or re-clog late fail to return to envelope;
+        # a burst-guided campaign finds one, and its (seed, knobs)
+        # handle replays red
+        from madsim_tpu import fuzz
+        from madsim_tpu.models.workloads import recovery
+        from madsim_tpu.search.mutate import apply_repro_knobs
+        inv = recovery_invariant(p99_le=ms(20), within=4, min_count=8)
+        rt = recovery("heal", invariant=inv)
+        res = fuzz(rt, max_steps=40000, batch=64, max_rounds=3,
+                   dry_rounds=4, chunk=512, burst_bonus=1.0)
+        rep = res["crash_repros"].get(CRASH_RECOVERY)
+        assert rep is not None, sorted(res["crash_repros"])
+        st = rt.init_batch(np.asarray([rep["seed"]], np.uint32))
+        st, _ = apply_repro_knobs(rt, st, rep["knobs"])
+        fin = rt.run_fused(st, 60000, 512)
+        assert int(np.asarray(fin.crash_code)[0]) == CRASH_RECOVERY
 
     def test_lane_burst_reads_deepest_transient_spike(self):
         # lane 0's spike lives in window 0 (p99 edge 4), lane 1's in

@@ -40,13 +40,13 @@ from madsim_tpu.service import (CorpusStore, prune_cold_entries,
 
 
 def _saturating_rt(**kw):
-    from bench import _make_saturating_runtime
-    return _make_saturating_runtime(**kw)
+    from madsim_tpu.models.workloads import saturating
+    return saturating(**kw)
 
 
 def _crashrich_rt(trace_cap=128):
-    from bench import _make_crashrich_runtime
-    return _make_crashrich_runtime("wal_kv", trace_cap=trace_cap)
+    from madsim_tpu.models.workloads import crashrich
+    return crashrich("wal_kv", trace_cap=trace_cap)
 
 
 KW = dict(max_steps=400, batch=16, max_rounds=3, dry_rounds=9, chunk=128)
@@ -100,12 +100,12 @@ class TestOneShardBitIdentity:
 
     @pytest.mark.slow
     def test_flagship_raft(self, tmp_path):
-        from bench import _make_runtime
+        from madsim_tpu.models.workloads import flagship_raft
         kw = dict(max_steps=512, batch=8, max_rounds=2, dry_rounds=9,
                   chunk=256)
         da, db = str(tmp_path / "a"), str(tmp_path / "b")
-        r1 = fuzz(_make_runtime(), corpus_dir=da, **kw)
-        r2 = fuzz_sharded(_make_runtime(), shards=1, corpus_dir=db, **kw)
+        r1 = fuzz(flagship_raft(), corpus_dir=da, **kw)
+        r2 = fuzz_sharded(flagship_raft(), shards=1, corpus_dir=db, **kw)
         assert r1["distinct_schedules"] == r2["distinct_schedules"]
         _assert_stores_equal(da, db)
 
@@ -433,7 +433,8 @@ class TestSupervisor:
                                         **dead})
 
         out = supervise_campaign(
-            "bench:_make_saturating_runtime", d, workers=2, segments=3,
+            "madsim_tpu.models.workloads:saturating", d, workers=2,
+            segments=3,
             rounds_per_segment=4, max_steps=100,
             run_segment=fake_segment)
         assert calls == [4, 8, 12]            # the rotation

@@ -37,14 +37,14 @@ from madsim_tpu.obs.timetravel import (ReplayDivergence, advance_exact,
 def _crashrich_rt(trace_cap=128):
     # trace_cap=128 SHARES executables with test_campaign/test_causal's
     # wal_kv runs (the r8 one-compile rule); trace_cap=4 is the
-    # wrapped-ring specimen --tt-smoke also builds
-    from bench import _make_crashrich_runtime
-    return _make_crashrich_runtime("wal_kv", trace_cap=trace_cap)
+    # wrapped-ring specimen
+    from madsim_tpu.models.workloads import crashrich
+    return crashrich("wal_kv", trace_cap=trace_cap)
 
 
 def _saturating_rt(**kw):
-    from bench import _make_saturating_runtime
-    return _make_saturating_runtime(**kw)
+    from madsim_tpu.models.workloads import saturating
+    return saturating(**kw)
 
 
 def _lane_tree(state, lane):
@@ -364,8 +364,8 @@ class TestTimeTravelExplain:
         # a lane the sweep left RUNNING (hit max_steps live) replays to
         # exactly its live dispatch count — not to halt, which would
         # honestly diverge the fingerprint and raise ReplayDivergence
-        from bench import _make_light_runtime
-        rt = _make_light_runtime(trace_cap=4)     # never halts, tiny ring
+        from madsim_tpu.models.workloads import light
+        rt = light(trace_cap=4)     # never halts, tiny ring
         log = CheckpointLog()
         state, _ = rt.run(rt.init_batch(np.arange(2)), 2048, 256,
                           ckpt_every=512, ckpt_log=log)
@@ -553,9 +553,9 @@ class TestFlagshipFidelity:
 @pytest.mark.slow
 class TestRaceFullChain:
     def test_confirmed_race_attaches_complete_chain(self):
-        from bench import _make_racy_runtime
         from madsim_tpu.analyze.races import confirm_race, find_races
-        rt = _make_racy_runtime(trace_cap=256)
+        from madsim_tpu.models.workloads import racy
+        rt = racy(trace_cap=256)
         seeds = np.arange(32, dtype=np.uint32)
         state = rt.run_fused(rt.init_batch(seeds), 20_000, 512)
         lanes = np.nonzero(np.asarray(state.crashed))[0]

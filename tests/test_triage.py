@@ -64,8 +64,8 @@ def grayfail_plan():
     """The frozen campaign's KnobPlan (REGRESSION.json: factory mix,
     dup_slots 2) — row-table source for attribution. Construction only;
     nothing compiles."""
-    from bench import _make_grayfail_runtime
-    rt = _make_grayfail_runtime("mix")
+    from madsim_tpu.models.workloads import grayfail
+    rt = grayfail("mix")
     return KnobPlan.from_runtime(rt, dup_slots=2)
 
 
@@ -299,8 +299,8 @@ class TestAttribution:
 # ---------------------------------------------------------------------------
 
 def _crashrich_rt():
-    from bench import _make_crashrich_runtime
-    return _make_crashrich_runtime("wal_kv", trace_cap=128)
+    from madsim_tpu.models.workloads import crashrich
+    return crashrich("wal_kv", trace_cap=128)
 
 
 class TestAudit:
@@ -354,6 +354,72 @@ class TestAudit:
         # bucket sorting below it can't make the rotation re-audit)
         assert load_audit(store)["cursor_key"] \
             == second["audited"][0]["bucket"]
+
+
+REGRESSION_ROOT = os.path.dirname(FROZEN)
+REGRESSION_CAMPAIGNS = sorted(
+    n for n in os.listdir(REGRESSION_ROOT)
+    if os.path.isdir(os.path.join(REGRESSION_ROOT, n)))
+
+
+class TestRegressionCorpus:
+    """The committed campaign dirs as a regression suite (OSS-Fuzz
+    style): every recorded crash bucket still reproduces under the
+    run-twice verify guard, and the top-energy corpus entries still land
+    on their recorded schedule hashes bit for bit — an engine change
+    that rewires replay fails here. REGRESSION.json names the factory
+    and the replay budget; the store's structural signature refuses a
+    runtime that would replay the knobs onto the wrong rows.
+
+    The stores were frozen under jax's earlier threefry key derivation
+    (`jax_threefry_partitionable=False`; jax 0.9.0 defaults it to True).
+    A (seed, knobs) handle names a trajectory only under the key
+    derivation it was recorded with, and the store signature does not
+    record it, so the replay pins that mode."""
+
+    TOP_ENTRIES = 8
+    CHUNK = 64      # fine enough that the recorded step budget binds
+
+    @pytest.mark.parametrize("name", REGRESSION_CAMPAIGNS)
+    def test_buckets_reproduce_and_entries_hit_recorded_hashes(
+            self, name, tmp_path):
+        import jax
+        d = str(tmp_path / name)        # replay never touches the fixture
+        shutil.copytree(os.path.join(REGRESSION_ROOT, name), d)
+        with jax.threefry_partitionable(False):
+            self._replay(name, d)
+
+    def _replay(self, name, d):
+        from madsim_tpu import replay_bucket
+        from madsim_tpu.parallel.stats import sched_hash_u64
+        from madsim_tpu.service.worker import resolve_factory
+        with open(os.path.join(d, "REGRESSION.json")) as f:
+            man = json.load(f)
+        rt = resolve_factory(man["factory"])(**man["factory_kwargs"])
+        dup, steps = int(man["dup_slots"]), int(man["max_steps"])
+        plan = KnobPlan.from_runtime(rt, dup_slots=dup)
+        store = CorpusStore(d, signature=store_signature(rt, plan),
+                            create=False)
+        keys = store.bucket_keys()
+        assert set(man["buckets"]) <= set(keys), "recorded bucket missing"
+        for key in keys:
+            crashed, code, _ = replay_bucket(rt, d, key, steps,
+                                             chunk=self.CHUNK,
+                                             dup_slots=dup, verify=True)
+            assert crashed, f"{name}/{key} no longer reproduces ({code})"
+        order = sorted(store.load_worker_state(0)["order"],
+                       key=lambda e: -e[1])[:self.TOP_ENTRIES]
+        assert order
+        for eid, _energy in order:
+            ent = store.load_entry(store._entry_name(int(eid)))
+            state = plan.apply(
+                rt.init_batch(np.asarray([ent["seed"]], np.uint32)),
+                KnobPlan.stack([ent["knobs"]]))
+            fin = rt.run_fused(state, steps, self.CHUNK)
+            got = int(sched_hash_u64(fin)[0])
+            assert got == ent["hash"], \
+                f"{name}: entry {eid} ran schedule {got:#x}, " \
+                f"recorded {ent['hash']:#x}"
 
 
 # ---------------------------------------------------------------------------
@@ -447,7 +513,8 @@ class TestSuperviseHooks:
         CorpusStore.load_entry = counting
         try:
             out = supervise_campaign(
-                "bench:_make_grayfail_runtime", frozen.dir, workers=1,
+                "madsim_tpu.models.workloads:grayfail", frozen.dir,
+                workers=1,
                 segments=3, rounds_per_segment=4, max_steps=100,
                 run_segment=fake_segment, observer=Rec())
             n_supervise = loads["n"]
