@@ -12,6 +12,28 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+
+def prefix_count(mask):
+    """`jnp.cumsum(mask.astype(int32))` over the last axis of a bool mask.
+
+    On TPU a cumsum lowers to a reduce-window as wide as the axis: O(C²)
+    adds on the VPU. For C > 32 the count is instead a 0/1 bf16 row times
+    a [C, C] upper-triangular ones matrix, accumulated in f32 on the MXU;
+    under vmap that is one [B, C] x [C, C] product. Every product is 0 or
+    1 and every sum an integer below 2**24, so the result is exact and
+    equal to the cumsum bit for bit. The form is chosen by the static
+    shape alone: masks of 32 or fewer slots (the threshold JAX itself uses
+    between its two cumsum forms on GPU) keep the cumsum, where a matmul's
+    fixed cost exceeds the O(C²) work.
+    """
+    c = mask.shape[-1]
+    if c <= 32:
+        return jnp.cumsum(mask.astype(jnp.int32), axis=-1)
+    tri = np.triu(np.ones((c, c), jnp.bfloat16))
+    return jnp.dot(mask.astype(jnp.bfloat16), tri,
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
 
 
 def masked_choice(key, mask):
@@ -19,12 +41,13 @@ def masked_choice(key, mask):
 
     Returns (idx:int32, valid:bool). idx is 0 when no entry is set (callers
     must gate on `valid`). Deterministic given `key` — this is the replayable
-    analog of mpsc.rs:75 `try_recv_random`.
+    analog of mpsc.rs:75 `try_recv_random`. The draw r is matched against
+    the mask's prefix count (`prefix_count`): the chosen index is the first
+    whose count reaches r + 1.
     """
-    mask = mask.astype(jnp.int32)
-    cnt = mask.sum()
+    cnt = mask.astype(jnp.int32).sum()
     r = jax.random.randint(key, (), 0, jnp.maximum(cnt, 1), dtype=jnp.int32)
-    cum = jnp.cumsum(mask)
+    cum = prefix_count(mask)
     idx = jnp.argmax(cum == r + 1).astype(jnp.int32)
     return idx, cnt > 0
 
@@ -87,14 +110,15 @@ def first_k_free(free_mask, k: int, scatter: bool = False):
     Returns (slots:int32[k], ok:bool[k]) where ok[j] is False when fewer than
     j+1 slots are free; not-ok rows return slot 0 (callers gate on ok).
 
-    Two lowerings, identical results (the emission_write knob,
-    types.py): the default cumsum rank-match is O(kC) compares — cheap on
-    the TPU VPU, but the k*C product is quadratic in cluster width when
-    k ~ n and C = 16n (DESIGN §5 width tax); `scatter=True` writes each
-    free slot's index into its rank row instead — one O(C) scatter, the
+    Both lowerings rank the free slots by their prefix count
+    (`prefix_count`) and give identical results (the emission_write knob,
+    types.py): the default rank-match is O(kC) compares — cheap on the
+    TPU VPU, but the k*C product is quadratic in cluster width when k ~ n
+    and C = 16n (DESIGN §5 width tax); `scatter=True` writes each free
+    slot's index into its rank row instead — one O(C) scatter, the
     CPU-friendly form.
     """
-    pos = jnp.cumsum(free_mask.astype(jnp.int32))
+    pos = prefix_count(free_mask)
     if scatter:
         C = free_mask.shape[0]
         rank = pos - 1

@@ -13,6 +13,7 @@ process may load the TPU library, and every xdist worker imports this file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -108,14 +109,79 @@ def test_fused_runner_shards_over_four_chips(sharded_runner):
         "step.check"}
 
 
-def test_emission_write_has_no_convolution(sharded_runner):
+_TYPED = re.compile(r"^\s*(?:ROOT\s+)?(%[^\s=]+)\s+=\s+(\w+)\[([\d,]*)\]"
+                    r"(\{[^}\s]*\})?\s+([\w-]+)\(([^)]*)\)")
+
+
+def _instructions(hlo: str) -> dict:
+    """{name: (dtype, dims, layout, opcode, operand names, line)} for
+    every array-valued instruction of a compiled module's text."""
+    out = {}
+    for line in hlo.splitlines():
+        m = _TYPED.match(line)
+        if m is not None:
+            name, dtype, dims, layout, opcode, args = m.groups()
+            dims = tuple(int(d) for d in dims.split(",") if d)
+            operands = [a.strip() for a in args.split(",")
+                        if a.strip().startswith("%")]
+            out[name] = (dtype, dims, layout or "", opcode, operands, line)
+    return out
+
+
+def test_emission_write_has_no_convolution(sharded_runner, flagship):
     """The one-hot lowering writes the event table with selects. An s32
     one-hot product there lowers to a convolution, which pins the payload
     table with the event rows minor and turns the pick's payload read into
-    a reduce across lanes."""
+    a reduce across lanes: no s32 convolution in `step.emit`, and no
+    `s32[..., C, payload_words]` value with the event rows minor."""
     from madsim_tpu.obs.scopes import op_scopes
     hlo = sharded_runner.as_text()
     scopes = op_scopes(hlo)
-    convs = [line.split("=", 1)[0].split()[-1] for line in hlo.splitlines()
-             if " convolution(" in line]
-    assert [c for c in convs if scopes[c] == "step.emit"] == []
+    instrs = _instructions(hlo)
+    C, P = flagship.cfg.event_capacity, flagship.cfg.payload_words
+    s32_convs = [n for n, (dt, _, _, op, args, _) in instrs.items()
+                 if op == "convolution" and scopes[n] == "step.emit"
+                 and "s32" in [dt] + [instrs[a][0] for a in args]]
+    assert s32_convs == []
+    c_minor = [n for n, (dt, dims, layout, _, _, _) in instrs.items()
+               if dt == "s32" and dims[-2:] == (C, P)
+               and layout.startswith("{1,2,0")]
+    assert c_minor == []
+
+
+def test_prefix_count_is_a_bf16_matmul(sharded_runner, flagship):
+    """The pick's tie-break and the emit's free-slot rank count a prefix
+    over the C event rows (`ops/select.prefix_count`). A cumsum there
+    lowers to an O(C²) reduce-window; the product of a 0/1 row with the
+    [C, C] triangular ones constant runs on the MXU instead. The only
+    convolutions in those phases are that product, and no reduce-window
+    spans the event rows."""
+    from madsim_tpu.obs.scopes import op_scopes
+    hlo = sharded_runner.as_text()
+    scopes = op_scopes(hlo)
+    instrs = _instructions(hlo)
+    C = flagship.cfg.event_capacity
+    phases = ("step.pick", "step.emit")
+    convs = {n: v for n, v in instrs.items()
+             if v[3] == "convolution" and scopes[n] in phases}
+    assert {scopes[n] for n in convs} == set(phases)
+    tri = ("bf16", (C, C))
+    for name, (dtype, dims, _, _, args, _) in convs.items():
+        assert dtype == "f32" and dims[-1] == C, name
+        kinds = [instrs[a][:2] for a in args]
+        assert len(kinds) == 2 and tri in kinds, (name, kinds)
+        kinds.remove(tri)
+        (row_dtype, row_dims), = kinds
+        assert row_dtype in ("bf16", "pred") and row_dims[-1] == C, kinds
+    # the [C, C] operand is the one constant, moved but never computed
+    square = [v for v in instrs.values() if v[:2] == tri]
+    assert "constant" in {v[3] for v in square}
+    assert {v[3] for v in square} <= {
+        "constant", "parameter", "get-tuple-element", "bitcast", "copy",
+        "copy-done", "fusion"}
+    assert all("calls=%bitcast_fusion" in v[5] for v in square
+               if v[3] == "fusion")
+    windows = [re.search(r"window=\{size=([\dx]+)", v[5]).group(1)
+               for n, v in instrs.items()
+               if v[3] == "reduce-window" and scopes[n] in phases]
+    assert [w for w in windows if str(C) in w.split("x")] == []

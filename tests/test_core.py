@@ -87,6 +87,69 @@ class TestSelectOps:
         assert np.asarray(rows).tolist() == [[0, 1, 2, 3], [8, 9, 10, 11]]
 
 
+
+PREFIX_WIDTHS = [1, 5, 16, 32, 33, 96, 256, 384]
+
+
+def _lane_masks(c: int, kind: str, lanes: int = 64):
+    """bool[lanes, c]: random rows of varied density, or all set, or none."""
+    if kind == "all":
+        return np.ones((lanes, c), bool)
+    if kind == "none":
+        return np.zeros((lanes, c), bool)
+    rng = np.random.default_rng(c)
+    return rng.random((lanes, c)) < rng.random((lanes, 1))
+
+
+class TestPrefixCount:
+    """`prefix_count` is a matmul above 32 slots and a cumsum at or below;
+    both must equal the cumsum bit for bit, and so must every pick and
+    free-slot rank built on it."""
+
+    @pytest.mark.parametrize("kind", ["random", "all", "none"])
+    @pytest.mark.parametrize("c", PREFIX_WIDTHS)
+    def test_equals_cumsum_under_vmap(self, c, kind):
+        mask = jnp.asarray(_lane_masks(c, kind))
+        got = jax.jit(jax.vmap(sel.prefix_count))(mask)
+        want = np.cumsum(np.asarray(mask), axis=-1, dtype=np.int32)
+        assert got.dtype == jnp.int32
+        assert (np.asarray(got) == want).all()
+
+    @pytest.mark.parametrize("c", PREFIX_WIDTHS)
+    def test_masked_choice_matches_cumsum_pick(self, c):
+        def cumsum_pick(key, mask):
+            m = mask.astype(jnp.int32)
+            cnt = m.sum()
+            r = jax.random.randint(key, (), 0, jnp.maximum(cnt, 1),
+                                   dtype=jnp.int32)
+            return jnp.argmax(jnp.cumsum(m) == r + 1).astype(jnp.int32), \
+                cnt > 0
+
+        masks = jnp.asarray(np.concatenate(
+            [_lane_masks(c, k, 32) for k in ("random", "all", "none")]))
+        keys = jax.vmap(prng.seed_key)(jnp.arange(masks.shape[0]))
+        idx, ok = jax.jit(jax.vmap(sel.masked_choice))(keys, masks)
+        ref_idx, ref_ok = jax.jit(jax.vmap(cumsum_pick))(keys, masks)
+        assert (np.asarray(ok) == np.asarray(ref_ok)).all()
+        assert (np.asarray(idx) == np.asarray(ref_idx)).all()
+
+    @pytest.mark.parametrize("scatter", [False, True])
+    @pytest.mark.parametrize("k", [1, 4, 8])
+    @pytest.mark.parametrize("c", [5, 32, 33, 96, 384])
+    def test_first_k_free_matches_reference(self, c, k, scatter):
+        free = np.concatenate(
+            [_lane_masks(c, kind, 32) for kind in ("random", "all", "none")])
+        slots, ok = jax.jit(jax.vmap(
+            lambda f: sel.first_k_free(f, k, scatter=scatter)))(
+                jnp.asarray(free))
+        for lane, row in enumerate(free):
+            idx = np.flatnonzero(row)[:k]
+            want = np.zeros(k, np.int32)
+            want[:idx.size] = idx
+            assert np.asarray(slots)[lane].tolist() == want.tolist()
+            assert np.asarray(ok)[lane].tolist() == \
+                (np.arange(k) < idx.size).tolist()
+
 def _pingpong_rt(n_nodes=3, target=5, **cfg_kw):
     cfg = SimConfig(n_nodes=n_nodes, time_limit=T.sec(30), **cfg_kw)
     return Runtime(cfg, [PingPong(n_nodes, target=target)], state_spec())
